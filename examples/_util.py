@@ -43,3 +43,12 @@ def sequence_default_edges():
 
 def arg(args, i, default, cast=int):
     return cast(args[i]) if len(args) > i else default
+
+
+def run_cli(main):
+    """Command-line entry: persistent compile cache on, then
+    ``main(argv)``. Tests call ``main`` directly and stay cache-free."""
+    from gelly_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    main(sys.argv[1:])

@@ -3,9 +3,7 @@
 Usage: python examples/bipartiteness_check.py [<edges path> <merge every chunks>]
 """
 
-import sys
-
-from _util import arg, stream_from_args
+from _util import arg, run_cli, stream_from_args
 
 from gelly_tpu.library.bipartiteness import bipartiteness_check, to_candidates
 
@@ -21,4 +19,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

@@ -7,9 +7,7 @@ vectorized axis on one device).
 Usage: python examples/broadcast_triangle_count.py [<edges path> <samples> <vertices>]
 """
 
-import sys
-
-from _util import arg, stream_from_args
+from _util import arg, run_cli, stream_from_args
 from window_triangles import DEFAULT
 
 from gelly_tpu.library.triangles import sampled_triangle_count
@@ -28,4 +26,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

@@ -5,10 +5,9 @@ Prints the final matching and its total weight plus net runtime, mirroring
 the reference's getNetRuntime report (:62-64).
 """
 
-import sys
 import time
 
-from _util import stream_from_args
+from _util import run_cli, stream_from_args
 
 from gelly_tpu.library.matching import weighted_matching
 
@@ -28,4 +27,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

@@ -85,9 +85,7 @@ resilient raw-fold driver, which has no codec/H2D pipeline or merge
 windows) is an error, not a silent no-op.
 """
 
-import sys
-
-from _util import arg, sequence_default_edges, stream_from_args
+from _util import arg, run_cli, sequence_default_edges, stream_from_args
 
 from gelly_tpu.library.connected_components import (
     connected_components,
@@ -505,4 +503,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

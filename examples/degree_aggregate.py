@@ -4,9 +4,7 @@ the BASELINE workload #1 surface.
 Usage: python examples/degree_aggregate.py [<edges path> <out|in|both>]
 """
 
-import sys
-
-from _util import arg, sequence_default_edges, stream_from_args
+from _util import arg, run_cli, sequence_default_edges, stream_from_args
 
 
 def main(args):
@@ -22,4 +20,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

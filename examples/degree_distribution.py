@@ -14,6 +14,8 @@ from gelly_tpu.core.io import EdgeChunkSource  # noqa: E402
 from gelly_tpu.core.stream import edge_stream_from_source  # noqa: E402
 from gelly_tpu.library.degrees import degree_distribution  # noqa: E402
 
+from _util import run_cli  # noqa: E402
+
 # ExamplesTestData.DEGREES_DATA (+/- events).
 DEFAULT = [
     (1, 2, 0), (2, 3, 0), (1, 4, 0), (2, 3, 1), (3, 4, 0), (1, 2, 1),
@@ -46,4 +48,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

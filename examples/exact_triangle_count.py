@@ -4,9 +4,7 @@ Usage: python examples/exact_triangle_count.py [<edges path>]
 Prints (vertex, count) pairs; key -1 is the global count.
 """
 
-import sys
-
-from _util import stream_from_args
+from _util import run_cli, stream_from_args
 from window_triangles import DEFAULT
 
 
@@ -22,4 +20,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

@@ -3,10 +3,8 @@
 Usage: python examples/iterative_connected_components.py [<edges path>]
 """
 
-import sys
-
 import numpy as np
-from _util import sequence_default_edges, stream_from_args
+from _util import run_cli, sequence_default_edges, stream_from_args
 
 from gelly_tpu.library.iterative_cc import IterativeCCStream
 
@@ -21,4 +19,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

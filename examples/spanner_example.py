@@ -3,9 +3,7 @@
 Usage: python examples/spanner_example.py [<edges path> <merge every chunks> <k>]
 """
 
-import sys
-
-from _util import arg, stream_from_args
+from _util import arg, run_cli, stream_from_args
 
 from gelly_tpu.library.spanner import spanner, spanner_edges
 
@@ -33,4 +31,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

@@ -4,10 +4,8 @@ Usage: python examples/window_triangles.py [<edges path> <window ms>]
 Edge values are event-time timestamps (the ITCase's format).
 """
 
-import sys
-
 import numpy as np
-from _util import arg, stream_from_args
+from _util import arg, run_cli, stream_from_args
 
 from gelly_tpu import TimeCharacteristic
 from gelly_tpu.library.triangles import window_triangles
@@ -35,4 +33,4 @@ def main(args):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:])
+    run_cli(main)

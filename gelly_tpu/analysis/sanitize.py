@@ -1,7 +1,7 @@
 """Sanitizer lane: run the native folds under ASan / UBSan.
 
 ``GELLY_NATIVE_SANITIZE=asan|ubsan`` makes ``utils/native.py`` build
-instrumented shared objects (separate ``lib<stem>.<mode>.so`` cache
+instrumented shared objects (separate ``lib<stem>.<mode>.<key>.so`` cache
 names). Loading one into a plain CPython requires the sanitizer runtime
 ahead of everything else, so this module prepares an ``LD_PRELOAD``
 environment (runtime discovered via ``g++ -print-file-name``) and drives

@@ -33,7 +33,7 @@ from ..engine.aggregation import (  # noqa: F401  (threshold re-exported)
     sparse_payload_id_check,
 )
 from ..ops import segments, unionfind
-from ..ops.pallas_kernels import on_tpu as pallas_on_tpu
+from ..ops.pallas_kernels import pallas_interpret
 
 
 class CCSummary(NamedTuple):
@@ -935,7 +935,7 @@ def connected_components(
     mode = resolve_merge_mode(merge_mode)
     # Static per-plan choice: jit specializes the fold on it, and the
     # engine's compiled-plan cache keys on agg.fold_backend.
-    interp = None if backend == "xla" else not pallas_on_tpu()
+    interp = None if backend == "xla" else pallas_interpret()
 
     def init() -> CCSummary:
         return CCSummary(

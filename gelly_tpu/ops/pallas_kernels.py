@@ -27,8 +27,9 @@ its throughput on dense tiled compute (MXU 128×128 systolic matmuls, VPU
 
 :func:`wedge_count_matrix` is the classic tiled Pallas matmul (grid over
 output tiles, full-K accumulation per tile, f32 on the MXU). Every kernel
-here takes ``interpret=`` (default: on whenever the attached platform is
-not a TPU) so the CPU CI exercises the exact same kernel code paths.
+here takes ``interpret=``; the default (:func:`pallas_interpret`) compiles
+on a TPU, interprets on the CPU (so the CPU CI exercises the exact same
+kernel code paths), and refuses any other platform.
 """
 
 from __future__ import annotations
@@ -39,18 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# Compat shim: the x64-toggle context manager lives at jax.enable_x64 on
-# newer jax and jax.experimental.enable_x64/disable_x64 on 0.4.x.
-if hasattr(jax, "enable_x64"):
-    def _x64_mode(enabled: bool):
-        return jax.enable_x64(enabled)
-else:  # jax 0.4.x
-    from jax.experimental import disable_x64 as _disable_x64
-    from jax.experimental import enable_x64 as _enable_x64
-
-    def _x64_mode(enabled: bool):
-        return _enable_x64() if enabled else _disable_x64()
 
 TILE = 128  # MXU native tile edge
 
@@ -70,10 +59,11 @@ def wedge_count_matrix(m: jax.Array,
                        interpret: bool | None = None) -> jax.Array:
     """W = MᵀM for a bool wedge mask M[u, x] — W[a, b] = common smaller
     neighbors of a and b. N must be a multiple of 128 (pad the mask).
-    ``interpret`` defaults to auto: compiled on TPU, interpreter
-    elsewhere (CPU pallas has no compile path)."""
+    ``interpret`` defaults to :func:`pallas_interpret`. The kernel holds
+    whole ``(n, 128)`` column blocks in VMEM, so it compiles only where
+    :func:`wedge_kernel_fits` (n <= 8064 on v5e)."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = pallas_interpret()
     n = m.shape[0]
     if n % TILE:
         raise ValueError(f"wedge matrix size {n} not a multiple of {TILE}")
@@ -82,7 +72,7 @@ def wedge_count_matrix(m: jax.Array,
     # The framework traces with x64 on (64-bit id space); Mosaic rejects the
     # i64 grid indices that leak into the index maps, so trace the kernel
     # itself in 32-bit mode — nothing here needs 64-bit.
-    with _x64_mode(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             _wedge_kernel,
             out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
@@ -96,8 +86,37 @@ def wedge_count_matrix(m: jax.Array,
         )(mf, mf)
 
 
+# Scoped VMEM a Mosaic kernel may use on v5e (the compiler's default
+# limit; it refuses n=8192 wedge blocks with "scoped vmem 16.12M > 16.00M").
+SCOPED_VMEM_BYTES = 16 << 20
+
+
+def wedge_kernel_fits(n: int) -> bool:
+    """Does :func:`wedge_count_matrix` compile for an ``n``-slot mask?
+    Each grid step double-buffers two f32 ``(n, 128)`` input blocks and
+    one ``(128, 128)`` output tile; the whole K axis is in every block."""
+    need = 2 * (2 * n * TILE * 4) + 2 * TILE * TILE * 4
+    return n % TILE == 0 and need <= SCOPED_VMEM_BYTES
+
+
+
 def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """The ``interpret=`` default of every kernel here: compiled on a TPU,
+    interpreted on the CPU. Any other platform is an error, never a
+    silent interpreter run on an accelerator."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile only for TPU and interpret only on the "
+        f"CPU; the attached platform is {platform!r}"
+    )
 
 
 # --------------------------------------------------------------------- #
@@ -208,7 +227,7 @@ def sorted_window_gather(table: jax.Array, sidx: jax.Array, *,
             f"bound {GATHER_MAX_VALUE} (values must stay below 2^24)"
         )
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = pallas_interpret()
     L = sidx.shape[0]
     if L == 0:
         return jnp.zeros((0,), jnp.int32)
@@ -235,7 +254,7 @@ def sorted_window_gather(table: jax.Array, sidx: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((tile, 1), lambda g, s: (g, 0)),
     )
-    with _x64_mode(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
             grid_spec=grid_spec,

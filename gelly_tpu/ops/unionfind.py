@@ -341,8 +341,9 @@ def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
       that round's hook and forced into the exact tail fixpoint — labels
       are identical to the XLA backend bit for bit. Requires a capacity
       :func:`~gelly_tpu.ops.pallas_kernels.gatherable` (multiple of the
-      window span, <= 2^24); ``interpret`` (default: auto off-TPU) runs
-      the kernel interpreted so CPU CI exercises the same code path.
+      window span, <= 2^24); ``interpret`` defaults to
+      :func:`~gelly_tpu.ops.pallas_kernels.pallas_interpret` (compiled on
+      TPU, interpreted on the CPU, an error elsewhere).
     """
     if backend not in ("xla", "pallas"):
         raise ValueError(f"backend must be xla/pallas, got {backend!r}")

@@ -26,17 +26,6 @@ SHARD_AXIS = "shards"
 # re-deriving launcher state. None on single-process / auto-detected runs.
 _COORDINATOR_ADDRESS: str | None = None
 
-# Compat shim: jax.shard_map graduated from jax.experimental.shard_map
-# (jax <= 0.4.x, where the replication-check kwarg is spelled check_rep)
-# to the top-level namespace (check_vma). Resolve once at import.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def initialize_multihost(coordinator_address: str | None = None,
                          num_processes: int | None = None,
@@ -50,18 +39,6 @@ def initialize_multihost(coordinator_address: str | None = None,
     is Flink's Netty shuffle; here it is XLA collectives over DCN). Under a
     standard TPU pod launcher the arguments auto-detect (pass nothing).
     """
-    import os
-
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        # Multi-process CPU runs (the MiniCluster-analog test tier) need an
-        # explicit cross-process collectives implementation on jax 0.4.x —
-        # without it the CPU backend rejects multiprocess computations.
-        # Newer jax selects this automatically; the knob may not exist
-        # there, hence the guard.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
     kw = {}
     if coordinator_address is not None:
         kw["coordinator_address"] = coordinator_address
@@ -118,9 +95,9 @@ def replicated_spec() -> P:
 
 def shard_map_fn(mesh: Mesh, fn, in_specs, out_specs, check_vma: bool = False):
     """Thin wrapper over jax.shard_map pinned to the stream mesh."""
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
+        check_vma=check_vma,
     )
 
 

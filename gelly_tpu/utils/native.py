@@ -1,7 +1,8 @@
 """ctypes bindings for the native runtime components.
 
 Builds the C++ sources under ``native/`` with g++ on first use (cached as
-shared objects next to the source; no pip/pybind dependency) and exposes
+shared objects next to the source, named by a hash of source and flags;
+no pip/pybind dependency) and exposes
 
 - :func:`parse_edge_list_file` — int64 COO arrays straight from disk, with
   the comment/whitespace conventions of the reference's readers
@@ -23,6 +24,7 @@ fall back to pure-numpy implementations.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -106,7 +108,7 @@ _u8p = ctypes.POINTER(ctypes.c_uint8)
 
 # Sanitizer lane (gelly_tpu/analysis/sanitize.py): GELLY_NATIVE_SANITIZE
 # selects an instrumented build of every native component. Sanitized
-# shared objects get their own cache names (lib<stem>.<mode>.so) so the
+# shared objects get their own cache names (lib<stem>.<mode>.<key>.so) so the
 # production .so never carries sanitizer runtime dependencies. Loading an
 # instrumented .so into a plain CPython requires the sanitizer runtime in
 # LD_PRELOAD — analysis/sanitize.py sets that up for its subprocess; a
@@ -130,27 +132,50 @@ def _sanitize_mode() -> str:
     return mode
 
 
+def _lib_path(stem: str, mode: str) -> tuple[str, list[str], str]:
+    """(source, g++ flags, shared-object path) for one native component.
+    The path carries a hash of the source bytes and the flags, so the
+    library that loads is always built from the source on disk — a
+    stale ``.so`` copied in with the tree can never match."""
+    src = os.path.join(_NATIVE_DIR, f"{stem}.cc")
+    flags = ["-O3", "-shared", "-fPIC"]
+    if mode:
+        flags.extend(_SANITIZE_FLAGS[mode])
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read())
+    digest.update(" ".join(flags).encode())
+    suffix = f".{mode}" if mode else ""
+    so = os.path.join(
+        _NATIVE_DIR, f"lib{stem}{suffix}.{digest.hexdigest()[:16]}.so"
+    )
+    return src, flags, so
+
+
 def _load_lib(stem: str) -> ctypes.CDLL:
-    """Compile native/<stem>.cc to lib<stem>.so (mtime-cached) and dlopen it."""
+    """Compile native/<stem>.cc to lib<stem>[.<mode>].<hash>.so (see
+    :func:`_lib_path`) on first use and dlopen it."""
+    mode = _sanitize_mode()
+    lib_key = (stem, mode)
+    lib = _libs.get(lib_key)
+    if lib is not None:
+        return lib
+    src, flags, so = _lib_path(stem, mode)
     with _lock:
-        mode = _sanitize_mode()
-        key = (stem, mode)
-        if key in _libs:
-            return _libs[key]
-        src = os.path.join(_NATIVE_DIR, f"{stem}.cc")
-        suffix = f".{mode}" if mode else ""
-        so = os.path.join(_NATIVE_DIR, f"lib{stem}{suffix}.so")
-        if not os.path.exists(so) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(so)
-        ):
-            cmd = ["g++", "-O3", "-shared", "-fPIC"]
-            if mode:
-                cmd.extend(_SANITIZE_FLAGS[mode])
-            cmd.extend(["-o", so, src])
-            subprocess.run(cmd, check=True, capture_output=True)
+        if lib_key in _libs:
+            return _libs[lib_key]
+        if not os.path.exists(so):
+            # Build under a private name and rename into place: concurrent
+            # builders (test workers) never dlopen a half-written file.
+            tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
+            try:
+                subprocess.run(["g++", *flags, "-o", tmp, src],
+                               check=True, capture_output=True)
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
         lib = ctypes.CDLL(so)
-        _libs[key] = lib
+        _libs[lib_key] = lib
         return lib
 
 

@@ -2,8 +2,9 @@
 
 Every kernel here runs under ``interpret=True`` on the CPU backend — the
 exact code path the TPU compiles — so tier-1 exercises the Pallas fold
-without hardware (the ISSUE's CI requirement). Shapes are deliberately
-tiny: the interpreter executes grid steps serially in Python.
+without hardware. Shapes are deliberately tiny: the interpreter executes
+grid steps serially in Python. ``tests/test_tpu_compile.py`` compiles the
+same kernels for a v5e at real widths.
 """
 
 import jax
@@ -306,3 +307,22 @@ def test_engine_plan_cache_keys_on_fold_backend():
     # Key layout: (device ids, axis names, fold_backend, merge_mode).
     assert {k[2] for k in agg._plan_cache} == {"xla", "pallas"}
     assert {k[3] for k in agg._plan_cache} == {"auto"}
+
+
+@pytest.mark.parametrize("platform, interpret", [
+    ("tpu", False), ("cpu", True), ("gpu", None),
+])
+def test_pallas_interpret_only_on_cpu(monkeypatch, platform, interpret):
+    # Compiled on a TPU, interpreted on the CPU; any other platform is an
+    # error, never a silent interpreter run on an accelerator.
+    from types import SimpleNamespace
+
+    from gelly_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(
+        jax, "devices", lambda: [SimpleNamespace(platform=platform)])
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            pk.pallas_interpret()
+    else:
+        assert pk.pallas_interpret() is interpret

@@ -1,0 +1,103 @@
+"""v5e ahead-of-time compiles of the main path's Pallas kernels.
+
+The TPU compiler is installed here and compiles for a described
+``v5e:2x2`` topology with no chip attached: it refuses what interpret
+mode accepts (VMEM overruns, unaligned blocks, i64 in a kernel). Every
+case passes ``interpret=False`` and asserts the kernel is in the
+compiled text. The topology is described only inside the ``topo``
+fixture (libtpu loads once per process; see the on-chip-measurement
+guide, section 2), and the persistent compile cache is off around the
+compiles (an AOT entry cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gelly_tpu.library import triangles
+from gelly_tpu.ops import pallas_kernels as pk
+
+TABLE = 1 << 24  # north-star vertex capacity
+LANES = 1 << 20  # sorted index lanes per call
+# Largest mask _pick_method lets the wedge kernel take (8064 on v5e).
+WEDGE_MAX_N = max(n for n in range(pk.TILE, 1 << 14, pk.TILE)
+                  if pk.wedge_kernel_fits(n))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_sorted_window_gather_compiles_at_north_star(one_chip):
+    c = _compile(
+        lambda t, i: pk.sorted_window_gather(t, i, interpret=False),
+        _spec((TABLE,), jnp.int32, one_chip),
+        _spec((LANES,), jnp.int32, one_chip),
+    )
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_blocked_gather_compiles_at_north_star(one_chip):
+    c = _compile(
+        lambda t, i: pk.blocked_gather(t, i, interpret=False),
+        _spec((TABLE,), jnp.int32, one_chip),
+        _spec((LANES,), jnp.int32, one_chip),
+    )
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_wedge_kernel_compiles_at_the_largest_auto_size(one_chip):
+    n = WEDGE_MAX_N
+    c = _compile(lambda m: pk.wedge_count_matrix(m, interpret=False),
+                 _spec((n, n), jnp.bool_, one_chip))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_wedge_kernel_refused_one_tile_past_the_bound(one_chip):
+    # The bound is the compiler's: one 128-tile more overruns scoped VMEM.
+    n = WEDGE_MAX_N + pk.TILE
+    assert not pk.wedge_kernel_fits(n)
+    with pytest.raises(Exception, match="vmem"):
+        _compile(lambda m: pk.wedge_count_matrix(m, interpret=False),
+                 _spec((n, n), jnp.bool_, one_chip))
+
+
+def test_auto_never_picks_mxu_past_the_bound(monkeypatch):
+    # Steer _pick_method as if on a TPU: dense 4096-slot windows take the
+    # MXU, 8192-slot windows (which the compiler refuses) take gather.
+    monkeypatch.setattr(pk, "on_tpu", lambda: True)
+    assert triangles._pick_method("auto", 4096)(4096) == "mxu"
+    assert triangles._pick_method("auto", WEDGE_MAX_N)(1 << 20) == "mxu"
+    assert triangles._pick_method("auto", 8192)(8192) == "gather"
+    assert triangles._pick_method("auto", 8192)(1 << 20) == "gather"

@@ -650,3 +650,25 @@ def test_stage_timer_report_concurrent_with_new_stages():
         for t in threads:
             t.join()
     assert errs == []
+
+
+def test_native_library_is_keyed_by_its_source(tmp_path, monkeypatch):
+    # The loaded .so is always built from the .cc on disk: an edit gives
+    # a new library name, and a stale binary under the old name (say,
+    # copied in with the tree) is never the one that loads.
+    from gelly_tpu.utils import native
+
+    src = os.path.join(native._NATIVE_DIR, "matching.cc")
+    with open(src) as f:
+        text = f.read()
+    (tmp_path / "matching.cc").write_text(text)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_libs", {})
+    _, _, stale = native._lib_path("matching", "")
+    (tmp_path / "matching.cc").write_text(text + "\n// edited\n")
+    _, _, fresh = native._lib_path("matching", "")
+    assert stale != fresh
+    with open(stale, "wb") as f:
+        f.write(b"not a shared object")
+    native._load_lib("matching")
+    assert os.path.exists(fresh)
