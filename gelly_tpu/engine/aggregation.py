@@ -2351,8 +2351,16 @@ def run_aggregation(
             if h2d_depth > 0:
                 transferred = prefetch(transferred, depth=h2d_depth,
                                        gauge=h2d_gauge)
+            transferred = iter(transferred)
             try:
-                for unit, k, seq, edges in transferred:
+                while True:
+                    # The fold dispatcher's wait for a staged, transferred
+                    # unit (the reader, codec and H2D stages behind it).
+                    with timer("consumer_wait"):
+                        item = next(transferred, None)
+                    if item is None:
+                        break
+                    unit, k, seq, edges = item
                     # Last-retired-chunk rule: a chunk counts toward the
                     # checkpoint position exactly when its fold is
                     # dispatched here; units still in the compress/H2D

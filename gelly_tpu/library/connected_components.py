@@ -32,6 +32,7 @@ from ..engine.aggregation import (  # noqa: F401  (threshold re-exported)
     SummaryAggregation,
     sparse_payload_id_check,
 )
+from ..obs.bus import get_bus
 from ..ops import segments, unionfind
 from ..ops.pallas_kernels import pallas_interpret
 
@@ -265,6 +266,13 @@ def connected_components_compact(
             "valid": np.asarray(chunk.valid),
         }
 
+    def _count_lanes(members: int, lanes: int) -> None:
+        # The share of shipped fold lanes that carry a member: the rest
+        # is bucket padding the device fold still gathers over.
+        bus = get_bus()
+        bus.inc("cc.fold_members", members)
+        bus.inc("cc.fold_lanes", lanes)
+
     def _combine_pairs_idx(av: np.ndarray, ar: np.ndarray):
         """Merge a group's pairs into one forest, with each pair's root
         reported as its INDEX in the output (wire format of the star fold:
@@ -318,10 +326,12 @@ def connected_components_compact(
         # coarse quantum already provides. Both the quantum and the floor
         # cap at m: a row can never exceed the compact capacity, so
         # small-M plans must not pad to the large-M granule.
-        return bucket_stack_payloads(
+        out = bucket_stack_payloads(
             rows, {"v": -1, "ri": 0, "newv": -1},
             min_bucket=min(1024, m), quantum=min(1 << 18, m),
         )
+        _count_lanes(sum(r["v"].shape[0] for r in rows), out["v"].size)
+        return out
 
     def stack_segments(payloads: list, groups: int = 1,
                        seq: int | None = None) -> dict:
@@ -368,7 +378,7 @@ def connected_components_compact(
         # vertices) run far below members (∝ touched vertices) — giving
         # each its own quantum ladder instead of the members' bucket was
         # measured as ~1/3 of the wire bytes at Twitter scale.
-        return bucket_stack_payloads(
+        out = bucket_stack_payloads(
             rows, {"m": -1, "len": 0, "newv": -1},
             min_bucket=min(1024, m), quantum=min(1 << 18, m),
             per_key={
@@ -376,6 +386,8 @@ def connected_components_compact(
                 "newv": (min(1024, m), min(1 << 16, m)),
             },
         )
+        _count_lanes(sum(r["m"].shape[0] for r in rows), out["m"].size)
+        return out
 
     def _append_vertex_of(s: CCCompactSummary, payload) -> jax.Array:
         # Shared decode-table append: rows carry their own base, so
@@ -392,15 +404,17 @@ def connected_components_compact(
     def fold_compressed(s: CCCompactSummary, payload) -> CCCompactSummary:
         # Leaves arrive [K, cap] from the engine's stacked dispatch, or
         # [cap] when a scan strips the batch axis (the device-bound bench).
-        vertex_of = _append_vertex_of(s, payload)
-        v = jnp.atleast_2d(payload["v"])
-        ri = jnp.atleast_2d(payload["ri"])  # row-local root indices
-        kb, capb = v.shape
-        ri_flat = (
-            ri + capb * jnp.arange(kb, dtype=jnp.int32)[:, None]
-        ).reshape(-1)
-        v = v.reshape(-1)
-        croot = unionfind.union_pairs_star(s.croot, v, ri_flat, v >= 0)
+        with jax.named_scope("cc.fold"):
+            with jax.named_scope("cc.decode"):
+                vertex_of = _append_vertex_of(s, payload)
+            v = jnp.atleast_2d(payload["v"])
+            ri = jnp.atleast_2d(payload["ri"])  # row-local root indices
+            kb, capb = v.shape
+            ri_flat = (
+                ri + capb * jnp.arange(kb, dtype=jnp.int32)[:, None]
+            ).reshape(-1)
+            v = v.reshape(-1)
+            croot = unionfind.union_pairs_star(s.croot, v, ri_flat, v >= 0)
         return CCCompactSummary(croot, vertex_of)
 
     def fold_segments(s: CCCompactSummary, payload) -> CCCompactSummary:
@@ -409,28 +423,32 @@ def connected_components_compact(
         # root-row index of every member lane is its segment START —
         # derived on device from the lengths' cumsum, replacing the
         # shipped per-pair ri (half the pair bytes on the H2D link).
-        vertex_of = _append_vertex_of(s, payload)
-        mm = jnp.atleast_2d(payload["m"])
-        ln = jnp.atleast_2d(payload["len"])
-        kb, capm = mm.shape
-        cum = jnp.cumsum(ln, axis=1)
-        total = cum[:, -1]
-        lane = jnp.arange(capm, dtype=jnp.int32)
-        # Segment of each lane = # cum entries <= lane (searchsorted
-        # right); clamp covers padding lanes past the last segment.
-        seg = jax.vmap(
-            lambda c: jnp.searchsorted(c, lane, side="right")
-        )(cum).astype(jnp.int32)
-        seg = jnp.minimum(seg, ln.shape[1] - 1)
-        starts = (cum - ln).astype(jnp.int32)
-        ri = jnp.take_along_axis(starts, seg, axis=1)
-        valid = lane[None, :] < total[:, None]
-        ri_flat = (
-            ri + capm * jnp.arange(kb, dtype=jnp.int32)[:, None]
-        ).reshape(-1)
-        croot = unionfind.union_pairs_star(
-            s.croot, mm.reshape(-1), ri_flat, valid.reshape(-1)
-        )
+        with jax.named_scope("cc.fold"):
+            with jax.named_scope("cc.decode"):
+                vertex_of = _append_vertex_of(s, payload)
+            mm = jnp.atleast_2d(payload["m"])
+            ln = jnp.atleast_2d(payload["len"])
+            kb, capm = mm.shape
+            with jax.named_scope("cc.segments"):
+                cum = jnp.cumsum(ln, axis=1)
+                total = cum[:, -1]
+                lane = jnp.arange(capm, dtype=jnp.int32)
+                # Segment of each lane = # cum entries <= lane
+                # (searchsorted right); clamp covers padding lanes past
+                # the last segment.
+                seg = jax.vmap(
+                    lambda c: jnp.searchsorted(c, lane, side="right")
+                )(cum).astype(jnp.int32)
+                seg = jnp.minimum(seg, ln.shape[1] - 1)
+                starts = (cum - ln).astype(jnp.int32)
+                ri = jnp.take_along_axis(starts, seg, axis=1)
+                valid = lane[None, :] < total[:, None]
+                ri_flat = (
+                    ri + capm * jnp.arange(kb, dtype=jnp.int32)[:, None]
+                ).reshape(-1)
+            croot = unionfind.union_pairs_star(
+                s.croot, mm.reshape(-1), ri_flat, valid.reshape(-1)
+            )
         return CCCompactSummary(croot, vertex_of)
 
     def combine(a: CCCompactSummary, b: CCCompactSummary) -> CCCompactSummary:
@@ -486,15 +504,20 @@ def connected_components_compact(
     def transform(s: CCCompactSummary) -> jax.Array:
         # The ONLY full-capacity op in the plan: materialize i32[n] labels
         # once per window close.
-        root = unionfind.pointer_jump(s.croot)
-        ok = s.vertex_of >= 0
-        canon = jnp.full((m,), segments.INT_MAX, jnp.int32).at[
-            jnp.where(ok, root, m)
-        ].min(jnp.where(ok, s.vertex_of, segments.INT_MAX), mode="drop")
-        lab_c = canon[root]
-        return jnp.full((n,), -1, jnp.int32).at[
-            jnp.where(ok, s.vertex_of, n)
-        ].set(jnp.where(ok, lab_c, -1), mode="drop")
+        with jax.named_scope("cc.close"):
+            with jax.named_scope("cc.close.jump"):
+                root = unionfind.pointer_jump(s.croot)
+            with jax.named_scope("cc.close.canon"):
+                ok = s.vertex_of >= 0
+                canon = jnp.full((m,), segments.INT_MAX, jnp.int32).at[
+                    jnp.where(ok, root, m)
+                ].min(jnp.where(ok, s.vertex_of, segments.INT_MAX),
+                      mode="drop")
+            with jax.named_scope("cc.close.labels"):
+                lab_c = canon[root]
+                return jnp.full((n,), -1, jnp.int32).at[
+                    jnp.where(ok, s.vertex_of, n)
+                ].set(jnp.where(ok, lab_c, -1), mode="drop")
 
     def flatten(s: CCCompactSummary) -> CCCompactSummary:
         # Cadenced path flatten: the star/rooted pair folds skip the

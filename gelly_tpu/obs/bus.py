@@ -87,6 +87,14 @@ Counter/gauge names are dotted, ``<subsystem>.<what>``:
                                       age deadline (``stack_ms=``);
                                       tail drains on flush()/close()
                                       are untagged
+``cc.fold_members``                   real member lanes of each stacked
+                                      compact-CC fold payload (sum of
+                                      its rows' lengths), counted where
+                                      the codec stacks it
+``cc.fold_lanes``                     lanes shipped for those payloads
+                                      after bucket padding (K x cap);
+                                      members / lanes is the fold's
+                                      lane fill
 ``engine.units_folded``               pipeline units retired by a fold
 ``engine.chunks_folded``              chunks inside those units
 ``engine.edges_folded``               valid edges (tracer-enabled runs)
@@ -258,6 +266,20 @@ only when a tracer is installed or :func:`recording` is on):
                                       capture + ring push + suffix
                                       query + transform (scales with
                                       pane size, not window length)
+====================================  =================================
+
+Profiler annotations (``jax.profiler.TraceAnnotation``; nothing is
+published to the bus — they exist only in a ``jax.profiler`` trace, on
+the host thread that ran the stage, beside the device's operations):
+
+====================================  =================================
+``gelly.<stage>``                     one span per ``StageTimer`` stage
+                                      entry: ``ingest_chunks``,
+                                      ``ingest_compress``, ``h2d``,
+                                      ``consumer_wait`` (the fold
+                                      dispatcher waiting for a staged,
+                                      transferred unit),
+                                      ``fold_dispatch``, ``merge_emit``
 ====================================  =================================
 
 Tests that need isolation wrap the block in :func:`scope`, which swaps

@@ -151,7 +151,8 @@ def _chase_roots(p: jax.Array, x: jax.Array) -> jax.Array:
         x_, g = st
         return g, p[g]
 
-    x, _ = jax.lax.while_loop(cond, body, (x, p[x]))
+    with jax.named_scope("uf.chase"):
+        x, _ = jax.lax.while_loop(cond, body, (x, p[x]))
     return x
 
 
@@ -177,13 +178,15 @@ def _rooted_fixpoint(parent: jax.Array, src: jax.Array, rv_fn,
         p, _ = state
         ru = _chase_roots(p, src)
         rv = rv_fn(p, ru)
-        lo = jnp.minimum(ru, rv)
-        hi = jnp.maximum(ru, rv)
-        live = valid & (lo != hi)
-        p2 = masked_scatter_min(p, hi, lo, live)
-        return p2, jnp.any(live)
+        with jax.named_scope("uf.hook"):
+            lo = jnp.minimum(ru, rv)
+            hi = jnp.maximum(ru, rv)
+            live = valid & (lo != hi)
+            p2 = masked_scatter_min(p, hi, lo, live)
+            return p2, jnp.any(live)
 
-    p, _ = jax.lax.while_loop(cond, body, (parent, live0))
+    with jax.named_scope("uf.fixpoint"):
+        p, _ = jax.lax.while_loop(cond, body, (parent, live0))
     return p
 
 
@@ -246,7 +249,9 @@ def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
        hook at an interior node would replace a real parent edge and
        disconnect its ancestors, losing earlier dispatches' unions. Two
        rounds (depths 2 then 3) measured fully convergent on Zipf
-       payload streams.
+       payload streams, not on Twitter-2010's degree law: there the exact
+       fixpoint (step 3) still ran 103 rounds over 64 dispatches on a
+       v5e (PERF.md §5).
     2. a depth-limited convergence check: equal depth-limited labels imply
        same tree (chases are deterministic), so ``any(live) == False`` here
        PROVES convergence and skips step 3 entirely (a while_loop whose
@@ -269,23 +274,25 @@ def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
         return g
 
     p = parent
-    for depth in fast_depths:
-        ru = chase_fixed(p, v, depth)
-        rv = ru[ri]
-        lo = jnp.minimum(ru, rv)
-        hi = jnp.maximum(ru, rv)
-        # Hook ONLY at verified roots: a depth-limited chase can stop at
-        # an interior node, and a scatter-min there would REPLACE its real
-        # parent edge — disconnecting its ancestor chain and silently
-        # splitting a component built by earlier dispatches (a root's
-        # self-loop is the only edge safe to overwrite). Pairs whose
-        # chase fell short stay live for the check below and resolve in
-        # the exact fixpoint.
-        live = valid & (lo != hi) & (p[hi] == hi)
-        p = masked_scatter_min(p, hi, lo, live)
+    with jax.named_scope("uf.fast"):
+        for depth in fast_depths:
+            ru = chase_fixed(p, v, depth)
+            rv = ru[ri]
+            lo = jnp.minimum(ru, rv)
+            hi = jnp.maximum(ru, rv)
+            # Hook ONLY at verified roots: a depth-limited chase can stop
+            # at an interior node, and a scatter-min there would REPLACE
+            # its real parent edge — disconnecting its ancestor chain and
+            # silently splitting a component built by earlier dispatches
+            # (a root's self-loop is the only edge safe to overwrite).
+            # Pairs whose chase fell short stay live for the check below
+            # and resolve in the exact fixpoint.
+            live = valid & (lo != hi) & (p[hi] == hi)
+            p = masked_scatter_min(p, hi, lo, live)
 
-    ru = chase_fixed(p, v, check_depth)
-    live0 = jnp.any(valid & (ru != ru[ri]))
+    with jax.named_scope("uf.check"):
+        ru = chase_fixed(p, v, check_depth)
+        live0 = jnp.any(valid & (ru != ru[ri]))
     return _rooted_fixpoint(p, v, lambda p_, ru_: ru_[ri], valid, live0)
 
 

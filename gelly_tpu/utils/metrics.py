@@ -4,10 +4,9 @@ The reference has none of this in-repo (SURVEY.md §5: only a
 ``getNetRuntime()`` printout, ``CentralizedWeightedMatching.java:62-64``;
 Flink's web UI is never referenced) — the TPU framework owns it instead:
 
-- :class:`StageTimer` — named accumulated wall-clock per pipeline stage;
+- :class:`StageTimer` — named accumulated wall-clock per pipeline stage,
+  each stage also a ``gelly.<stage>`` host span on the profiler's clock;
 - :class:`ThroughputMeter` — edges/sec over a window of samples;
-- :func:`metered` — wrap any chunk iterator to count edges + time without
-  touching the pipeline;
 - :func:`trace` — context manager around ``jax.profiler`` for device traces.
 """
 
@@ -16,9 +15,7 @@ from __future__ import annotations
 import contextlib
 import time
 from collections import defaultdict
-from typing import Iterable, Iterator
-
-import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class StageTimer:
@@ -27,6 +24,12 @@ class StageTimer:
     Thread-safe: ingest stages are timed concurrently from prefetch worker
     threads while the consumer times fold/merge, so the read-modify-write
     accumulation takes a lock.
+
+    Every stage entry is also a ``gelly.<stage>`` host annotation
+    (``jax.profiler.TraceAnnotation``): under a profiler trace the stage
+    shows beside the device events on the trace's own clock, on the
+    thread that ran it; with no trace running it costs one inactive
+    TraceMe.
     """
 
     def __init__(self):
@@ -38,14 +41,15 @@ class StageTimer:
 
     @contextlib.contextmanager
     def __call__(self, stage: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.totals[stage] += dt
-                self.counts[stage] += 1
+        with TraceAnnotation("gelly." + stage):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                with self._lock:
+                    self.totals[stage] += dt
+                    self.counts[stage] += 1
 
     def report(self) -> dict[str, dict[str, float]]:
         # Snapshot under the lock before building the report: iterating
@@ -166,13 +170,6 @@ class ThroughputMeter:
         """Feed the current reading into an ``obs`` registry as gauges."""
         bus.gauge(f"{prefix}.edges", self.edges)
         bus.gauge(f"{prefix}.edges_per_sec", round(self.edges_per_sec, 1))
-
-
-def metered(chunks: Iterable, meter: ThroughputMeter) -> Iterator:
-    """Pass-through chunk iterator feeding ``meter`` with valid-edge counts."""
-    for c in chunks:
-        meter.record(int(np.asarray(c.valid).sum()))
-        yield c
 
 
 @contextlib.contextmanager

@@ -5,7 +5,7 @@ import os
 import numpy as np
 import pytest
 
-from gelly_tpu.utils.metrics import StageTimer, ThroughputMeter, metered
+from gelly_tpu.utils.metrics import StageTimer, ThroughputMeter
 from gelly_tpu.utils.prefetch import prefetch
 
 
@@ -222,16 +222,6 @@ def test_stage_timer_publish_gauges():
     t.totals["fold_dispatch"] = 1.25
     t.publish(bus)
     assert bus.snapshot()["gauges"]["stage.fold_dispatch.busy_s"] == 1.25
-
-
-def test_metered_stream_counts_valid_edges(reference_edges):
-    from gelly_tpu import edge_stream_from_edges
-
-    s = edge_stream_from_edges(reference_edges, vertex_capacity=16, chunk_size=3)
-    m = ThroughputMeter()
-    n = sum(1 for _ in metered(iter(s), m))
-    assert n == 3  # ceil(7/3) chunks
-    assert m.edges == 7
 
 
 def _native_available():
