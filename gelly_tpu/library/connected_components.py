@@ -161,6 +161,31 @@ def merge_chunk_forest(glob: np.ndarray, lab: np.ndarray) -> np.ndarray:
     return glob
 
 
+def lane_segment_starts(ln: jax.Array, capm: int):
+    """Segment start of every member lane on the segment wire.
+
+    ``ln`` is i32[K, capr]: each row's segment lengths in lane order,
+    zero-padded (a zero may also sit between segments). Returns ``(ri,
+    valid)``, both [K, capm]: ``ri`` is the first lane of the segment
+    holding each lane, ``valid`` marks lanes below the row's total.
+
+    Each segment's length is scatter-added at its END lane (the next
+    segment's start), so a running sum along the lanes reads, at every
+    lane, the end of the last segment closed at or before it — its own
+    segment's start. O(lanes), no search over the lengths. A full row's
+    last end falls past the lanes and drops; ``valid`` masks the lanes
+    at and past the total.
+    """
+    cum = jnp.cumsum(ln, axis=1, dtype=jnp.int32)
+    rows = jnp.arange(ln.shape[0], dtype=jnp.int32)[:, None]
+    ends = jnp.zeros((ln.shape[0], capm), jnp.int32).at[rows, cum].add(
+        ln.astype(jnp.int32), mode="drop"
+    )
+    ri = jnp.cumsum(ends, axis=1, dtype=jnp.int32)
+    valid = jnp.arange(capm, dtype=jnp.int32)[None, :] < cum[:, -1:]
+    return ri, valid
+
+
 def connected_components_compact(
     vertex_capacity: int, merge: str = "gather",
     compact_capacity: int | None = None, wire: str = "auto",
@@ -199,10 +224,12 @@ def connected_components_compact(
       per merge-window unit runs the dedup-blocked two-level combine and
       emits members grouped by component, each component's root FIRST in
       its segment. The device derives every pair's root-row index as its
-      segment start, so the pair wire is 4 bytes/member + one length per
-      component — half the ``"pairs"`` bytes — and the per-chunk numpy
-      group-combine disappears. ``unit_block`` is the cache-blocking
-      granule of the level-1 pass (2^18 edges measured fastest).
+      segment start, with one scatter of segment ends and a running sum
+      along the lanes (:func:`lane_segment_starts`), so the pair wire is
+      4 bytes/member + one length per component — half the ``"pairs"``
+      bytes — and the per-chunk numpy group-combine disappears.
+      ``unit_block`` is the cache-blocking granule of the level-1 pass
+      (2^18 edges measured fastest).
     - ``"pairs"`` — the per-chunk sparse combine + (v, root-index) pair
       rows (round 4's format; the no-native-toolchain fallback).
     - ``"auto"`` (default) — segments when the native codec is available.
@@ -421,8 +448,9 @@ def connected_components_compact(
         # Segment wire: members [K, capm] grouped by component, each
         # component's root FIRST in its segment; lengths [K, capr]. The
         # root-row index of every member lane is its segment START —
-        # derived on device from the lengths' cumsum, replacing the
-        # shipped per-pair ri (half the pair bytes on the H2D link).
+        # derived on device by a scatter of segment ends and a running
+        # sum along the lanes, replacing the shipped per-pair ri (half
+        # the pair bytes on the H2D link).
         with jax.named_scope("cc.fold"):
             with jax.named_scope("cc.decode"):
                 vertex_of = _append_vertex_of(s, payload)
@@ -430,19 +458,7 @@ def connected_components_compact(
             ln = jnp.atleast_2d(payload["len"])
             kb, capm = mm.shape
             with jax.named_scope("cc.segments"):
-                cum = jnp.cumsum(ln, axis=1)
-                total = cum[:, -1]
-                lane = jnp.arange(capm, dtype=jnp.int32)
-                # Segment of each lane = # cum entries <= lane
-                # (searchsorted right); clamp covers padding lanes past
-                # the last segment.
-                seg = jax.vmap(
-                    lambda c: jnp.searchsorted(c, lane, side="right")
-                )(cum).astype(jnp.int32)
-                seg = jnp.minimum(seg, ln.shape[1] - 1)
-                starts = (cum - ln).astype(jnp.int32)
-                ri = jnp.take_along_axis(starts, seg, axis=1)
-                valid = lane[None, :] < total[:, None]
+                ri, valid = lane_segment_starts(ln, capm)
                 ri_flat = (
                     ri + capm * jnp.arange(kb, dtype=jnp.int32)[:, None]
                 ).reshape(-1)

@@ -277,6 +277,82 @@ def test_unit_segments_root_first_invariant():
     assert np.array_equal(roots, mins)
 
 
+@pytest.mark.parametrize("lengths, capm", [
+    ([[3, 1, 4, 2]], 12),                      # one row, padding lanes
+    ([[2, 2], [1, 0], [4, 0]], 6),             # several rows, trailing 0s
+    ([[2, 0, 0, 3, 0, 1, 0, 0]], 8),           # zeros mid-row and trailing
+    ([[0, 0, 0], [3, 2, 0]], 7),               # an all-zero row
+    ([[4, 4], [5, 3]], 8),                     # full rows: total == capm
+    ([[1, 1, 1, 1, 1, 0]], 9),                 # single-member segments
+    ([[1, 3, 1, 0, 2, 1, 1, 0]], 9),           # full, with a zero inside
+])
+def test_lane_segment_starts_matches_search(lengths, capm):
+    """Every valid lane's segment start equals a search over the
+    lengths' cumsum (numpy ``searchsorted``, side right); lanes at and
+    past each row's total are invalid."""
+    import jax.numpy as jnp
+
+    from gelly_tpu.library.connected_components import lane_segment_starts
+
+    ln = np.asarray(lengths, np.int32)
+    ri, valid = lane_segment_starts(jnp.asarray(ln), capm)
+    ri, valid = np.asarray(ri), np.asarray(valid)
+    lane = np.arange(capm)
+    for k in range(ln.shape[0]):
+        cum = np.cumsum(ln[k])
+        seg = np.searchsorted(cum, lane, side="right")
+        want = (cum - ln[k])[np.minimum(seg, ln.shape[1] - 1)]
+        ok = lane < cum[-1]
+        assert np.array_equal(valid[k], ok)
+        assert np.array_equal(ri[k][ok], want[ok])
+
+
+def test_lane_segment_starts_random_rows():
+    """Random length rows with zero-length segments anywhere, padded to
+    one lane count like the wire's buckets: the same starts as the
+    segments laid out on the host, on every valid lane."""
+    import jax.numpy as jnp
+
+    from gelly_tpu.library.connected_components import lane_segment_starts
+
+    rng = np.random.default_rng(11)
+    capm = 160
+    for _ in range(20):
+        ln = rng.integers(0, 5, size=(3, 40)).astype(np.int32)
+        ln[rng.random((3, 40)) < 0.3] = 0
+        ri, valid = lane_segment_starts(jnp.asarray(ln), capm)
+        ri, valid = np.asarray(ri), np.asarray(valid)
+        for k in range(3):
+            starts = np.repeat(np.cumsum(ln[k]) - ln[k], ln[k])
+            t = starts.shape[0]
+            assert np.array_equal(valid[k], np.arange(capm) < t)
+            assert np.array_equal(ri[k][:t], starts)
+
+
+def test_segment_wire_many_small_components():
+    """Many small components put many segments in every row, so a lane
+    given another segment's start would join two components: the segment
+    wire's labels must still equal the oracle's."""
+    from gelly_tpu.library.connected_components import (
+        connected_components_compact,
+    )
+
+    rng = np.random.default_rng(29)
+    block = rng.integers(0, N_V // 4, 3000)
+    src = (4 * block + rng.integers(0, 4, 3000)).astype(np.int64)
+    dst = (4 * block + rng.integers(0, 4, 3000)).astype(np.int64)
+    oracle = cc_labels_numpy(src.astype(np.int32), dst.astype(np.int32),
+                             None, N_V)
+    agg = connected_components_compact(N_V, compact_capacity=N_V,
+                                       wire="segments")
+    lab = np.asarray(
+        _stream(src, dst).aggregate(
+            agg, mesh=mesh_lib.make_mesh(1), merge_every=4, fold_batch=2
+        ).result()
+    )
+    assert np.array_equal(lab, oracle)
+
+
 def test_compact_rerun_same_agg_instance():
     # on_run_start must reset the session: a second run with the same agg
     # re-assigns ids from scratch (fresh device state needs fresh newv).

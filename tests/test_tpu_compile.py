@@ -1,4 +1,5 @@
-"""v5e ahead-of-time compiles of the main path's Pallas kernels.
+"""v5e ahead-of-time compiles of the main path's Pallas kernels, and of
+the segment fold's lane derivation.
 
 The TPU compiler is installed here and compiles for a described
 ``v5e:2x2`` topology with no chip attached: it refuses what interpret
@@ -91,6 +92,19 @@ def test_wedge_kernel_refused_one_tile_past_the_bound(one_chip):
     with pytest.raises(Exception, match="vmem"):
         _compile(lambda m: pk.wedge_count_matrix(m, interpret=False),
                  _spec((n, n), jnp.bool_, one_chip))
+
+
+def test_lane_segment_starts_compiles_without_a_loop(one_chip):
+    # The segment fold's lane derivation at the file-fed cell's fold shape
+    # (1,572,864 member lanes, 2^19 lengths): one scatter and a running
+    # sum, no while loop (a search over the lengths would be a loop of
+    # log2(capr) steps over every lane).
+    from gelly_tpu.library.connected_components import lane_segment_starts
+
+    c = _compile(lambda ln: lane_segment_starts(ln, 6 << 18),
+                 _spec((1, 1 << 19), jnp.int32, one_chip))
+    text = c.as_text()
+    assert " scatter(" in text and " while(" not in text
 
 
 def test_auto_never_picks_mxu_past_the_bound(monkeypatch):
