@@ -22,6 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.chunk import EdgeChunk
+from ..obs.bus import get_bus
 from ..ops import segments
 from ..parallel import mesh as mesh_lib, partition
 from ..parallel.mesh import SHARD_AXIS
@@ -64,16 +65,17 @@ def degree_aggregate(vertex_capacity: int, count_out: bool = True,
         return jnp.zeros((n,), jnp.int64)
 
     def fold(deg, chunk):
-        delta = jnp.where(chunk.event == 1, -1, 1).astype(jnp.int64)
-        if count_out:
-            deg = segments.masked_scatter_add(
-                deg, chunk.src, delta, chunk.valid
-            )
-        if count_in:
-            deg = segments.masked_scatter_add(
-                deg, chunk.dst, delta, chunk.valid
-            )
-        return deg
+        with jax.named_scope("deg.fold"):
+            delta = jnp.where(chunk.event == 1, -1, 1).astype(jnp.int64)
+            if count_out:
+                deg = segments.masked_scatter_add(
+                    deg, chunk.src, delta, chunk.valid
+                )
+            if count_in:
+                deg = segments.masked_scatter_add(
+                    deg, chunk.dst, delta, chunk.valid
+                )
+            return deg
 
     def host_compress(chunk):
         m = np.asarray(chunk.valid)
@@ -114,18 +116,20 @@ def degree_aggregate(vertex_capacity: int, count_out: bool = True,
     def host_compress_sparse(chunk) -> dict:
         m = np.asarray(chunk.valid)
         ev = np.asarray(chunk.event)
+        n_valid = int(np.count_nonzero(m))
         from ..utils import native
 
         if native.sparse_codecs_available():
             v, d = native.degree_chunk_deltas_sparse(
                 np.asarray(chunk.src), np.asarray(chunk.dst),
-                ev if ev.any() else None, None if m.all() else m,
+                ev if ev.any() else None, None if n_valid == m.size else m,
                 n, count_out, count_in,
             )
         else:
             v, d = degree_pairs_numpy(
                 chunk.src, chunk.dst, ev, m, n, count_out, count_in
             )
+        get_bus().inc("deg.codec_edges", n_valid)
         return {"v": v, "d": d}
 
     def stack_sparse(payloads: list, groups: int = 1) -> dict:
@@ -148,18 +152,25 @@ def degree_aggregate(vertex_capacity: int, count_out: bool = True,
             payloads, groups, combine,
             {"v": np.empty(0, np.int32), "d": np.empty(0, np.int64)},
         )
-        return bucket_stack_payloads(payloads, {"v": -1, "d": 0})
+        out = bucket_stack_payloads(payloads, {"v": -1, "d": 0})
+        # Pairs over shipped lanes is the fold's lane fill; pairs over
+        # the codec's edges is its compression.
+        bus = get_bus()
+        bus.inc("deg.fold_pairs", sum(q["v"].shape[0] for q in payloads))
+        bus.inc("deg.fold_lanes", out["v"].size)
+        return out
 
     def fold_compressed_sparse(deg, payload):
         # payload: {"v": i32[K, cap], "d": int[K, cap]} counted (vertex,
         # net-delta) pairs, -1-padded. "d" is i32 straight from the
         # per-chunk codec but i64 after the group pre-combine (cross-chunk
         # sums exceed the per-chunk bound) — do NOT narrow it here.
-        v = payload["v"].reshape(-1)
-        ok = v >= 0
-        return segments.masked_scatter_add(
-            deg, jnp.where(ok, v, 0), payload["d"].reshape(-1), ok
-        )
+        with jax.named_scope("deg.fold"):
+            v = payload["v"].reshape(-1)
+            ok = v >= 0
+            return segments.masked_scatter_add(
+                deg, jnp.where(ok, v, 0), payload["d"].reshape(-1), ok
+            )
 
     if windowed is not None and int(windowed) < 1:
         raise ValueError(f"windowed must be >= 1 pane, got {windowed}")

@@ -95,6 +95,18 @@ Counter/gauge names are dotted, ``<subsystem>.<what>``:
                                       after bucket padding (K x cap);
                                       members / lanes is the fold's
                                       lane fill
+``deg.codec_edges``                   valid edges the sparse degree
+                                      codec compressed
+                                      (``host_compress_sparse``)
+``deg.fold_pairs``                    real (vertex, delta) pairs of
+                                      each stacked sparse degree
+                                      payload, counted where
+                                      ``stack_sparse`` stacks it
+``deg.fold_lanes``                    lanes shipped for those payloads
+                                      after bucket padding (K x cap);
+                                      pairs / lanes is the fold's lane
+                                      fill, pairs / codec edges the
+                                      codec's compression
 ``engine.units_folded``               pipeline units retired by a fold
 ``engine.chunks_folded``              chunks inside those units
 ``engine.edges_folded``               valid edges (tracer-enabled runs)

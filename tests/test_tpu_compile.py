@@ -1,5 +1,5 @@
-"""v5e ahead-of-time compiles of the main path's Pallas kernels, and of
-the segment fold's lane derivation.
+"""v5e ahead-of-time compiles of the main path's Pallas kernels, of
+the segment fold's lane derivation, and of the sparse degree fold.
 
 The TPU compiler is installed here and compiles for a described
 ``v5e:2x2`` topology with no chip attached: it refuses what interpret
@@ -105,6 +105,22 @@ def test_lane_segment_starts_compiles_without_a_loop(one_chip):
                  _spec((1, 1 << 19), jnp.int32, one_chip))
     text = c.as_text()
     assert " scatter(" in text and " while(" not in text
+
+
+def test_degree_fold_compiles_at_the_cell_shape(one_chip):
+    # The sparse degree fold at the degree cell's shape: one masked
+    # int64 scatter-add of a 2^21-lane pair bucket into i64[41,652,230]
+    # (the v5e emulates the 64-bit add as u32 halves with a carry).
+    from gelly_tpu.library.degrees import degree_aggregate
+
+    n = 41_652_230
+    agg = degree_aggregate(n, codec="sparse")
+    c = _compile(agg.fold_compressed, _spec((n,), jnp.int64, one_chip),
+                 {"v": _spec((1, 1 << 21), jnp.int32, one_chip),
+                  "d": _spec((1, 1 << 21), jnp.int32, one_chip)})
+    text = c.as_text()
+    assert " scatter(" in text and " while(" not in text
+    assert "/deg.fold/" in text
 
 
 def test_auto_never_picks_mxu_past_the_bound(monkeypatch):
