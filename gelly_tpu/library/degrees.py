@@ -156,8 +156,13 @@ def degree_aggregate(vertex_capacity: int, count_out: bool = True,
         # Pairs over shipped lanes is the fold's lane fill; pairs over
         # the codec's edges is its compression.
         bus = get_bus()
-        bus.inc("deg.fold_pairs", sum(q["v"].shape[0] for q in payloads))
+        pairs = sum(q["v"].shape[0] for q in payloads)
+        bus.inc("deg.fold_pairs", pairs)
         bus.inc("deg.fold_lanes", out["v"].size)
+        # Uncombined i32 deltas: at most ``groups`` rows, one a device,
+        # so each fold takes fold_compressed_sparse's i32 scatter.
+        bus.inc("deg.fold_i32_pairs",
+                pairs if out["d"].dtype == np.int32 else 0)
         return out
 
     def fold_compressed_sparse(deg, payload):
@@ -167,10 +172,20 @@ def degree_aggregate(vertex_capacity: int, count_out: bool = True,
         # sums exceed the per-chunk bound) — do NOT narrow it here.
         with jax.named_scope("deg.fold"):
             v = payload["v"].reshape(-1)
+            d = payload["d"].reshape(-1)
             ok = v >= 0
-            return segments.masked_scatter_add(
-                deg, jnp.where(ok, v, 0), payload["d"].reshape(-1), ok
-            )
+            if d.dtype == jnp.int32 and payload["d"].shape[0] == 1:
+                # One per-chunk row names each vertex at most once, so an
+                # i32 scatter into zeros is exact; the widened delta then
+                # takes one i64 add. The TPU has no 64-bit integer unit
+                # and runs the i64 scatter-add ~10x slower.
+                delta = segments.masked_scatter_add(
+                    jnp.zeros(deg.shape, jnp.int32), v, d, ok
+                )
+                return deg + delta.astype(deg.dtype)
+            # Group-combined i64 rows, or several i32 rows that may share
+            # a vertex: summing those in i32 could overflow.
+            return segments.masked_scatter_add(deg, v, d, ok)
 
     if windowed is not None and int(windowed) < 1:
         raise ValueError(f"windowed must be >= 1 pane, got {windowed}")

@@ -107,6 +107,10 @@ Counter/gauge names are dotted, ``<subsystem>.<what>``:
                                       pairs / lanes is the fold's lane
                                       fill, pairs / codec edges the
                                       codec's compression
+``deg.fold_i32_pairs``                those of the pairs whose
+                                      payload kept the per-chunk i32
+                                      deltas, one row a fold: the
+                                      fold's i32 scatter took them
 ``engine.units_folded``               pipeline units retired by a fold
 ``engine.chunks_folded``              chunks inside those units
 ``engine.edges_folded``               valid edges (tracer-enabled runs)

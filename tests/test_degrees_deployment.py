@@ -49,11 +49,12 @@ def _edge_file(tmp_path, seed):
     return path, src, dst
 
 
-def _run(path):
+def _run(path, **executor):
     stream = edge_stream_from_sharded_file(path, N_V, shards=1,
                                            chunk_size=CHUNK)
     out = stream.aggregate(degree_aggregate(N_V, codec="sparse"),
-                           mesh=make_mesh(1), merge_every=MERGE_EVERY)
+                           mesh=make_mesh(1), merge_every=MERGE_EVERY,
+                           **executor)
     return [np.asarray(w) for w in out]
 
 
@@ -105,6 +106,85 @@ def test_degree_counters_equal_the_chunks(tmp_path):
     assert c["deg.fold_pairs"] == pairs
     assert c["deg.fold_lanes"] == lanes
     assert pairs < lanes < 2 * pairs + 1024
+
+
+@pytest.mark.parametrize("fold_batch,i32_share", [(1, 1), (MERGE_EVERY, 0)])
+def test_i32_fold_counter_follows_the_payload_dtype(tmp_path, fold_batch,
+                                                    i32_share):
+    # Executor defaults ship each chunk's i32 deltas, one row a fold, and
+    # every pair takes the i32 scatter; fold_batch > 1 combines a group's
+    # chunks into one i64 row, which keeps the int64 scatter-add.
+    path, src, dst = _edge_file(tmp_path, 29)
+    with obs.scope() as bus:
+        windows = _run(path, fold_batch=fold_batch)
+        c = dict(bus.counters)
+    np.testing.assert_array_equal(windows[-1],
+                                  reference.labels(src, dst, N_V))
+    assert c["deg.fold_pairs"] > 0
+    assert c["deg.fold_i32_pairs"] == i32_share * c["deg.fold_pairs"]
+
+
+def _pairs(rng, n, k, pad):
+    """One codec row: ``k`` distinct vertices of ``[0, n)`` with i32
+    deltas, then ``pad`` lanes of -1 padding."""
+    v = np.full(k + pad, -1, np.int32)
+    v[:k] = rng.choice(n, k, replace=False)
+    d = np.zeros(k + pad, np.int32)
+    d[:k] = rng.integers(-40, 40, k)
+    return v, d
+
+
+def _fold_case(name):
+    """(state, payload) for one case of the sparse degree fold."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n, big = 4096, 2**31 - 1
+    state = rng.integers(0, 1000, n).astype(np.int64)
+    if name == "i32_one_row":
+        # deletions, and garbage behind padding lanes that must not land
+        v, d = _pairs(rng, n, 700, 324)
+        d[:50] = -rng.integers(1, 5, 50)
+        d[700:] = 7
+        return state, {"v": v[None], "d": d[None]}
+    if name == "i32_three_rows_share_vertices":
+        rows = [_pairs(rng, 64, 40, 24) for _ in range(3)]
+        v = np.stack([r[0] for r in rows])
+        d = np.stack([r[1] for r in rows])
+        v[:, 0], d[:, 0] = 5, big  # 3 x (2^31 - 1) on one vertex
+        return state, {"v": v, "d": d}
+    if name == "i32_state_near_2_40":
+        v, d = _pairs(rng, n, 900, 124)
+        d[:450:2], d[1:450:2] = big, -big
+        state += 2**40
+        return state, {"v": v[None], "d": d[None]}
+    assert name == "i64_group_combined"
+    agg = degree_aggregate(n, codec="sparse")
+    chunks = []
+    for _ in range(3):
+        v, d = _pairs(rng, n, 600, 0)
+        v[0], d[0] = 11, big
+        chunks.append({"v": v, "d": d})
+    payload = agg.stack_payloads(chunks, 1)
+    assert payload["d"].dtype == np.int64 and payload["d"].shape[0] == 1
+    return state, payload
+
+
+@pytest.mark.parametrize("name", ["i32_one_row",
+                                  "i32_three_rows_share_vertices",
+                                  "i32_state_near_2_40",
+                                  "i64_group_combined"])
+def test_sparse_fold_equals_an_int64_reference(name):
+    state, payload = _fold_case(name)
+    v, d = payload["v"].reshape(-1), payload["d"].reshape(-1)
+    want = state.copy()
+    np.add.at(want, v[v >= 0], d[v >= 0].astype(np.int64))
+    agg = degree_aggregate(state.shape[0], codec="sparse")
+    got = np.asarray(jax.jit(agg.fold_compressed)(
+        jax.numpy.asarray(state),
+        {k: jax.numpy.asarray(x) for k, x in payload.items()}))
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, want)
+    # every case but the first leaves a degree past what i32 holds
+    assert want.max() > 2**31 or name == "i32_one_row"
 
 
 def _op_names(fn, *args) -> set:

@@ -107,20 +107,35 @@ def test_lane_segment_starts_compiles_without_a_loop(one_chip):
     assert " scatter(" in text and " while(" not in text
 
 
-def test_degree_fold_compiles_at_the_cell_shape(one_chip):
-    # The sparse degree fold at the degree cell's shape: one masked
-    # int64 scatter-add of a 2^21-lane pair bucket into i64[41,652,230]
-    # (the v5e emulates the 64-bit add as u32 halves with a carry).
+def _degree_fold_text(one_chip, delta_dtype) -> list:
+    # The sparse degree fold at the degree cell's shape: a 2^21-lane
+    # pair bucket into i64[41,652,230]. Returns the compiled scatters.
     from gelly_tpu.library.degrees import degree_aggregate
 
     n = 41_652_230
     agg = degree_aggregate(n, codec="sparse")
     c = _compile(agg.fold_compressed, _spec((n,), jnp.int64, one_chip),
                  {"v": _spec((1, 1 << 21), jnp.int32, one_chip),
-                  "d": _spec((1, 1 << 21), jnp.int32, one_chip)})
+                  "d": _spec((1, 1 << 21), delta_dtype, one_chip)})
     text = c.as_text()
     assert " scatter(" in text and " while(" not in text
     assert "/deg.fold/" in text
+    return [ln for ln in text.splitlines() if " scatter(" in ln]
+
+
+def test_degree_fold_compiles_at_the_cell_shape(one_chip):
+    # The per-chunk i32 payload scatters on s32 into a zero vector, not
+    # on the u32 halves with a carry that the v5e's int64 scatter is.
+    scatters = _degree_fold_text(one_chip, jnp.int32)
+    assert all("s32[41652230]" in ln and "u32[" not in ln
+               for ln in scatters), scatters
+
+
+def test_degree_fold_compiles_for_group_combined_payloads(one_chip):
+    # An i64 (group-combined) payload keeps the int64 scatter-add, which
+    # the v5e emulates on u32 halves.
+    scatters = _degree_fold_text(one_chip, jnp.int64)
+    assert any("u32[41652230]" in ln for ln in scatters), scatters
 
 
 def test_auto_never_picks_mxu_past_the_bound(monkeypatch):
