@@ -3,15 +3,14 @@
 Usage: python chip_smoke.py [--chips 4]
 
 One process drives the system's main path through the entry points a
-user calls, at the north-star width (``vertex_capacity = 2^24``, the
-``bench_cc_large`` configuration), and checks every result against an
-independent reference:
+user calls, at the north-star width (``vertex_capacity = 2^24``), and
+checks every result against an independent reference:
 
   a. device check — a TPU or a non-zero exit, never a CPU run;
   b. file-fed streaming CC (compact codec, pipelined executor at its
      defaults) over 2^26 Zipf edges vs the pure-numpy oracle;
-  c. the raw device fold, compiled for ``fold_backend`` xla and pallas
-     (the pallas plan must hold a compiled ``tpu_custom_call``);
+  c. the raw (codec-off) device fold at 2^22-edge chunks, the
+     sort-dedup path, vs the oracle;
   d. window triangles where ``method="auto"`` picks the MXU kernel, vs
      ``method="gather"``;
   e. the served path: an ``IngestServer`` fed client-compressed STACKED
@@ -39,7 +38,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 DATA_DIR = os.path.join(HERE, ".smoke_data")  # gitignored, removed after
 
-# Phase sizes at the north-star width (bench.py bench_cc_large).
+# Phase sizes at the north-star width.
 FULL = {
     "n_v": 1 << 24,
     "n_e": 1 << 26,
@@ -58,6 +57,20 @@ FULL = {
     "mesh_merge_every": 8,
 }
 SEED = 17
+
+
+def synth_edges(num_edges: int, num_vertices: int, seed: int = 7):
+    """Power-law-ish edge stream (Zipf endpoints, the skew CC cares about).
+
+    Emits i32 ids: they are dense in [0, num_vertices), so the identity
+    vertex table passes them through zero-copy."""
+    rng = np.random.default_rng(seed)
+    # Zipf over a permuted id space so hot vertices are spread across slots.
+    a = 1.3
+    src = rng.zipf(a, size=num_edges) % num_vertices
+    dst = rng.zipf(a, size=num_edges) % num_vertices
+    perm = rng.permutation(num_vertices)
+    return perm[src].astype(np.int32), perm[dst].astype(np.int32)
 
 
 def emit(obj: dict) -> None:
@@ -206,7 +219,7 @@ def phase_stream_cc(sz: dict, edges, state: dict) -> dict:
 
 
 def phase_raw_fold(sz: dict, edges) -> dict:
-    """(c) raw device fold, xla vs pallas backend, compiled."""
+    """(c) raw device fold, compiled, vs the oracle."""
     import jax
 
     from gelly_tpu.core.chunk import make_chunk
@@ -219,29 +232,21 @@ def phase_raw_fold(sz: dict, edges) -> dict:
                    capacity=c)
         for i in range(sz["raw_chunks"])
     ]
-    on_tpu = jax.devices()[0].platform == "tpu"
-    out, line = {}, {}
-    for backend in ("xla", "pallas"):
-        agg = connected_components(sz["n_v"], fold_backend=backend)
-        state = agg.init()
-        plan = jax.jit(agg.fold, donate_argnums=0).lower(
-            state, chunks[0]).compile()
-        kernel = "tpu_custom_call" in plan.as_text()
-        if backend == "pallas" and on_tpu and not kernel:
-            raise AssertionError("pallas fold plan holds no tpu_custom_call")
-        mem = plan.memory_analysis()
-        line[f"{backend}_temp_bytes"] = (
-            None if mem is None else int(mem.temp_size_in_bytes))
-        line[f"{backend}_kernel"] = kernel
-        for ch in chunks:
-            state = plan(state, ch)
-        out[backend] = np.asarray(agg.transform(state))
+    agg = connected_components(sz["n_v"])
+    state = agg.init()
+    plan = jax.jit(agg.fold, donate_argnums=0).lower(
+        state, chunks[0]).compile()
+    mem = plan.memory_analysis()
+    for ch in chunks:
+        state = plan(state, ch)
+    labels = np.asarray(agg.transform(state))
     n_used = sz["raw_chunk"] * sz["raw_chunks"]
     oracle = oracle_labels(src[:n_used], dst[:n_used], sz["n_v"])
     return {
-        "parity": bool(np.array_equal(out["xla"], out["pallas"])
-                       and np.array_equal(out["xla"], oracle)),
-        "edges": n_used, **line,
+        "parity": bool(np.array_equal(labels, oracle)),
+        "edges": n_used,
+        "xla_temp_bytes": (
+            None if mem is None else int(mem.temp_size_in_bytes)),
     }
 
 
@@ -442,7 +447,6 @@ def main(argv=None) -> int:
               "error": f"--chips {args.chips} but {device['count']} found"})
         return 2
 
-    from bench import synth_edges
     from gelly_tpu.utils.compile_cache import enable_compile_cache
 
     emit({"phase": "compile_cache", "dir": enable_compile_cache(),

@@ -25,7 +25,7 @@ unified CLI (``python -m gelly_tpu.analysis plancheck [paths]``).
   key tuple. A knob read but not keyed means mutating it on a live
   instance silently returns the STALE compiled plan. Callable fields
   are exempt (the per-instance cache ties executables to the closure
-  identities; ``fold_backend`` is their keyed proxy), as are reads
+  identities), as are reads
   that only feed a refusal (``raise`` bodies and ``if``-tests guarding
   nothing but a ``raise``) and the documented label field ``name``.
 - ``PC102`` unvalidated string key component: a ``str``-typed knob that
@@ -89,7 +89,7 @@ declarative table of eligibility predicates x plan entry points — each
 for every predicate combination the table marks unsupported (the raise
 must sit under ``if``-tests whose identifiers — chased through simple
 assignments, and through same-module callees like
-``resolve_fold_backend`` — cover the predicate's tokens). A new entry
+``resolve_merge_mode`` — cover the predicate's tokens). A new entry
 path that forgets one refusal fails the lane:
 
 - ``PC401`` entry point lost a required refusal.
@@ -289,15 +289,11 @@ REFUSAL_MATRIX: dict[tuple[str, str], dict[str, frozenset]] = {
             frozenset({"requires_codec", "compressed"}),
     },
     ("connected_components.py", "connected_components"): {
-        "unknown fold_backend": frozenset({"fold_backend"}),
         "unknown merge_mode": frozenset({"merge_mode"}),
-    },
-    ("connected_components.py", "cc_tenant_tier"): {
-        "unknown fold_backend": frozenset({"fold_backend"}),
     },
 }
 # How deep the same-module callee expansion follows plain-name calls
-# (cc_tenant_tier -> connected_components -> resolve_fold_backend).
+# (connected_components -> resolve_merge_mode is one step).
 _MATRIX_CALL_DEPTH = 3
 
 # Home package (parent-directory basename) of each matrix module: when

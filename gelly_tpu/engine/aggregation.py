@@ -161,13 +161,6 @@ class SummaryAggregation:
     # restored device summary.
     on_run_start: Callable[[], None] | None = None
     on_resume: Callable[[Summary], None] | None = None
-    # Device-fold kernel backend the plan's fold closures were built for
-    # ("xla" | "pallas"): set by the library plan builders (e.g.
-    # connected_components(fold_backend=...)), recorded here so the
-    # engine's compiled-plan cache keys on it — the same aggregation
-    # instance re-jits (rather than silently reusing stale executables)
-    # if a caller rebuilds its folds for a different backend.
-    fold_backend: str = "xla"
     # Cross-shard window-merge strategy ("replicated" | "delta" | "auto").
     # The replicated merges (butterfly / hierarchical tree / gather) move
     # FULL per-shard summaries — cost ∝ capacity per window regardless of
@@ -189,8 +182,8 @@ class SummaryAggregation:
     # gathered rows (S * bucket) stay under ``merge_delta_auto_rows``,
     # else the plan's replicated merge. Deltas are measured against a
     # window-fresh locals (init()), which the engine guarantees by
-    # rebuilding locals at every window close. Like fold_backend, the
-    # compiled-plan cache keys on merge_mode.
+    # rebuilding locals at every window close. The compiled-plan cache
+    # keys on merge_mode, so mutating it on a live instance re-jits.
     merge_mode: str = "replicated"
     merge_delta: Callable[..., Summary] | None = None
     merge_dirty_count: Callable[[Summary], Any] | None = None
@@ -479,7 +472,7 @@ def _compiled_plan(agg: SummaryAggregation, m):
     # plancheck PC101 contract): a knob read but not keyed means mutating
     # it on a live instance silently returns the stale compiled plan.
     key = (tuple(d.id for d in m.devices.flat), m.axis_names,
-           agg.fold_backend, agg.merge_mode, agg.merge_degree,
+           agg.merge_mode, agg.merge_degree,
            agg.merge_delta_auto_rows, agg.transient,
            agg.fold_accumulates, agg.transform_may_alias,
            agg.jit_transform)
@@ -821,7 +814,7 @@ def _compiled_tenant_plan(agg: SummaryAggregation, lanes: int,
             )
         mesh_key = (tuple(d.id for d in mesh.devices.flat), mesh.axis_names)
         sharding = NamedSharding(mesh, P(SHARD_AXIS))
-    key = ("tenants", lanes, agg.fold_backend, agg.merge_mode, mesh_key)
+    key = ("tenants", lanes, agg.merge_mode, mesh_key)
     per_agg = agg.__dict__.setdefault("_plan_cache", {})
     if key in per_agg:
         return per_agg[key]
@@ -906,7 +899,6 @@ def run_aggregation(
     checkpoint_every: int = 1,
     resume: bool = False,
     prefetch_depth: int | None = None,
-    device_fields: tuple[str, ...] | None = None,
     host_precombine: Callable | None = None,
     fold_batch: int = 1,
     ingest_workers: int | None = None,
@@ -932,11 +924,6 @@ def run_aggregation(
     value is honored exactly — it is the caller's bound on in-flight
     staged units (host/device memory ∝ depth × unit size), and capping it
     below the worker count deliberately idles workers for memory.
-
-    ``device_fields`` names chunk fields to device_put on the prefetch
-    thread (e.g. ``("src", "dst", "valid")`` for CC): the H2D of exactly
-    the fields the fold reads then overlaps compute, while unused fields
-    stay host-side (jit prunes dead args, so they are never transferred).
 
     ``host_precombine(chunk) -> chunk`` runs on the prefetch thread before
     staging — an ingest-side partial pre-aggregation (e.g.
@@ -1884,8 +1871,8 @@ def run_aggregation(
             # chunk on the host, and jit prunes dead arguments at
             # dispatch so only the fields the fold actually reads are
             # transferred. (The merge_every path's precombine and
-            # device_fields H2D staging live in stage_unit/h2d_unit;
-            # this iterator feeds window mode only.)
+            # codec H2D staging live in stage_unit/h2d_unit; this
+            # iterator feeds window mode only.)
             nonlocal chunks_consumed
             for chunk in prefetch(iter(stream), prefetch_depth):
                 # In window mode checkpoints fire only here, at chunk
@@ -2167,14 +2154,6 @@ def run_aggregation(
                     else:
                         dev = jax.device_put(payload)
                     jax.block_until_ready(dev)
-                elif device_fields:
-                    dev = payload._replace(**{
-                        f: jax.device_put(getattr(payload, f))
-                        for f in device_fields
-                    })
-                    jax.block_until_ready(
-                        [getattr(dev, f) for f in device_fields]
-                    )
                 else:
                     dev = payload
             if tracer is not None:

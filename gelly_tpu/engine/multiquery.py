@@ -487,7 +487,6 @@ def fuse(queries, *, name: str | None = None,
         transform_may_alias=any(
             q.accum and q.agg.transform is None for q in specs
         ),
-        fold_backend="fused",
         merge_mode="replicated",
         name=plan_name,
         queries=specs,

@@ -19,8 +19,8 @@ The engine here owns that multiplexing natively:
 - **Capacity tiers**: tenants are admitted into named tiers, each tier
   one ``SummaryAggregation`` plan (its ``vertex_capacity`` is the
   tier's capacity class) and one chunk capacity; all tenants of a tier
-  share one compiled program per lane width, keyed like
-  ``fold_backend``/``merge_mode`` in the engine's plan cache.
+  share one compiled program per lane width, keyed by lane width and
+  ``merge_mode`` in the engine's plan cache.
 - **Fair-share windowing** (:class:`MultiTenantEngine`): per-tenant
   chunk queues; every scheduling round advances each backlogged tenant
   by at most one chunk, and a tenant with nothing pending contributes

@@ -34,7 +34,6 @@ from ..engine.aggregation import (  # noqa: F401  (threshold re-exported)
 )
 from ..obs.bus import get_bus
 from ..ops import segments, unionfind
-from ..ops.pallas_kernels import pallas_interpret
 
 
 class CCSummary(NamedTuple):
@@ -808,37 +807,9 @@ def _cc_merge_delta(n: int):
     return merge_delta, merge_dirty_count
 
 
-def resolve_fold_backend(fold_backend: str, vertex_capacity: int) -> str:
-    """Shared ``fold_backend=`` knob semantics: validate and resolve
-    ``"auto"``/``"xla"``/``"pallas"`` for the raw device fold.
-
-    ``"auto"`` resolves to ``"xla"``: the Pallas path's profitability is
-    hardware-dependent (it trades MXU flops for HBM random-touch latency;
-    see the bench's ``gather_study`` block), so the measured sweep — not
-    a heuristic — should flip the default. ``"pallas"`` validates the
-    capacity against the kernel's window-blocking requirements up front,
-    at plan-build time, instead of failing mid-stream.
-    """
-    if fold_backend not in ("auto", "xla", "pallas"):
-        raise ValueError(
-            f"fold_backend must be auto/xla/pallas, got {fold_backend!r}"
-        )
-    if fold_backend == "pallas":
-        from ..ops.pallas_kernels import gatherable
-
-        if not gatherable(vertex_capacity):
-            raise ValueError(
-                f"fold_backend='pallas' needs a window-blockable vertex "
-                f"capacity (multiple of 128 lanes spanning >= 2 windows, "
-                f"<= 2^24); got {vertex_capacity}"
-            )
-        return "pallas"
-    return "xla"
-
-
 def cc_tenant_tier(
     vertex_capacity: int, chunk_capacity: int = 1 << 10,
-    fold_backend: str = "auto", delta_auto_rows: int | None = None,
+    delta_auto_rows: int | None = None,
     compressed: bool = False, codec: str = "auto",
 ) -> tuple[SummaryAggregation, int]:
     """Build a CC plan suitable for one multi-tenant capacity tier
@@ -861,8 +832,7 @@ def cc_tenant_tier(
     """
     agg = connected_components(
         vertex_capacity, merge="gather", ingest_combine=compressed,
-        codec=codec,
-        fold_backend=fold_backend, delta_auto_rows=delta_auto_rows,
+        codec=codec, delta_auto_rows=delta_auto_rows,
     )
     return agg, int(chunk_capacity)
 
@@ -870,8 +840,7 @@ def cc_tenant_tier(
 def connected_components(
     vertex_capacity: int, merge: str = "tree", ingest_combine: bool = True,
     codec: str = "auto", compact_capacity: int | None = None,
-    fold_backend: str = "auto", merge_mode: str = "auto",
-    delta_auto_rows: int | None = None,
+    merge_mode: str = "auto", delta_auto_rows: int | None = None,
     windowed: int | None = None, ttl_panes: int | None = None,
 ) -> SummaryAggregation:
     """Build the CC aggregation over a slot space of ``vertex_capacity``.
@@ -911,26 +880,13 @@ def connected_components(
     dirty ``(slot, parent)`` entries (merge ∝ hooks, not capacity),
     ``"replicated"`` keeps the full-summary merge, ``"auto"`` (default)
     measures the dirty count each window close and picks per window.
-    Like ``fold_backend``, the engine's compiled-plan cache keys on it.
+    The engine's compiled-plan cache keys on it.
 
     ``delta_auto_rows`` overrides the ``"auto"`` crossover bound (max
     gathered delta rows before the replicated merge wins). Default is
-    the ``capacity / 4`` structural guess; the bench's
-    ``merge_delta_crossover`` block measures the real crossover per
-    chip against the ``engine.window_dirty_rows`` gauge — pass the
-    calibrated value here (``BENCH_tenants_r01.json`` records one for
-    the CPU mesh).
-
-    ``fold_backend`` picks the RAW device fold's kernel backend
-    (:func:`resolve_fold_backend`): ``"pallas"`` routes the large-chunk
-    sort-dedup fold's sorted chases through the VMEM-blocked gather
-    kernel (:func:`~gelly_tpu.ops.pallas_kernels.sorted_window_gather`,
-    exact — window misses fall through to the exact tail fixpoint);
-    ``"auto"`` stays on XLA until the recorded bench sweep says
-    otherwise. The codec plans' device folds are pair/star folds that
-    never run the raw dedup kernel, so the knob only shapes the
-    codec-off fold path (window mode, ``ingest_combine=False``, and the
-    device-bound bench).
+    the ``capacity / 4`` structural guess; the
+    ``engine.window_dirty_rows`` gauge reports each window's dirty
+    count, against which a deployment can set its own bound here.
 
     ``windowed=W`` marks the plan for the engine's sliding pane ring
     (``run_aggregation(windowed=...)``): emissions cover the last W
@@ -970,11 +926,7 @@ def connected_components(
         merge_mode = "replicated"
     n = vertex_capacity
     sparse = resolve_sparse_codec(codec, n)
-    backend = resolve_fold_backend(fold_backend, n)
     mode = resolve_merge_mode(merge_mode)
-    # Static per-plan choice: jit specializes the fold on it, and the
-    # engine's compiled-plan cache keys on agg.fold_backend.
-    interp = None if backend == "xla" else pallas_interpret()
 
     def init() -> CCSummary:
         return CCSummary(
@@ -984,10 +936,10 @@ def connected_components(
     def fold(s: CCSummary, chunk) -> CCSummary:
         if chunk.capacity >= RAW_DEDUP_MIN_CHUNK:
             # Large-chunk raw path: sort-dedup + verified hook rounds +
-            # compacted exact tail (union_edges_dedup) — ~10x the generic
-            # fixpoint at Twitter-scale capacity (its O(capacity) random
-            # doubling per round was the measured cost). Caps are perf
-            # knobs only; overflow falls back to the exact fixpoint.
+            # compacted exact tail (union_edges_dedup), which spends no
+            # O(capacity) random doubling per round, unlike the generic
+            # fixpoint. Caps are perf knobs only; overflow falls back to
+            # the exact fixpoint.
             parent = unionfind.union_edges_dedup(
                 s.parent, chunk.src, chunk.dst, chunk.valid,
                 # 3/16 of the chunk covers the distinct-pair counts of
@@ -996,7 +948,6 @@ def connected_components(
                 # with this cap, and overflow only costs speed (exact
                 # full-width fallback), never correctness.
                 unique_cap=max(1 << 20, 3 * (chunk.capacity >> 4)),
-                backend=backend, interpret=interp,
             )
         else:
             parent = unionfind.union_edges(
@@ -1152,15 +1103,13 @@ def connected_components(
         ),
         fold_accumulates=True,  # CC forests are pure edge-set summaries
         flatten=flatten,
-        fold_backend=backend,
         merge_mode=mode,
         merge_delta=_mk_delta,
         merge_dirty_count=_mk_count,
         # Auto threshold: delta rows cost ~8 bytes each on the wire +
         # pair-rate union work; past capacity/4 gathered rows the full
-        # replicated merge's sequential-scan unions win. The bench's
-        # merge_delta_crossover block measures the real bound per chip;
-        # delta_auto_rows carries the calibrated value in.
+        # replicated merge's sequential-scan unions win. delta_auto_rows
+        # carries a deployment's own bound in.
         merge_delta_auto_rows=(
             None if windowed is not None
             else n // 4 if delta_auto_rows is None
@@ -1174,8 +1123,8 @@ def connected_components(
 
 
 def cc_query(vertex_capacity: int, *, name: str = "cc",
-             merge: str = "gather", fold_backend: str = "auto",
-             compressed: bool = False, codec: str = "auto"):
+             merge: str = "gather", compressed: bool = False,
+             codec: str = "auto"):
     """Fuse-compatible CC query (``engine.multiquery.fuse``), tagged
     with this plan's slot capacity so ``fuse`` can refuse mismatched
     chunk schemas.
@@ -1196,8 +1145,7 @@ def cc_query(vertex_capacity: int, *, name: str = "cc",
         name=name,
         agg=connected_components(vertex_capacity, merge=merge,
                                  ingest_combine=compressed,
-                                 codec=codec,
-                                 fold_backend=fold_backend),
+                                 codec=codec),
         slot_capacity=vertex_capacity,
     )
 

@@ -22,8 +22,8 @@ directory is recorded under ``otherData.jax_profiler`` when
 ``utils.metrics.trace(log_dir, tracer=...)`` ran around the same run),
 so the two timelines can be opened side by side and matched.
 
-:func:`validate_chrome_trace` is the schema check the tests and the
-bench artifact path share — load-bearing validation, not a smoke print.
+:func:`validate_chrome_trace` is the schema check the tests apply to
+every exported trace — load-bearing validation, not a smoke print.
 
 Multi-host stitching: each host of a coordinated run exports its own
 trace file (one ring per process; ``otherData.host`` carries the

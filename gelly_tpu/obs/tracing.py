@@ -11,9 +11,8 @@ Overhead contract (ISSUE 5): tracer NOT installed ⇒ zero allocations on
 the pipeline's unit path. The engine binds ``tracer = active_tracer()``
 once per run and guards every site with ``if tracer is not None`` — no
 span objects, no kwargs dicts, not even a clock read when disabled.
-Installed ⇒ one dict + one deque append per span, measured <2% eps on
-the ``streaming_cc_large`` capture (the ``obs`` block in bench.py
-records tracer-on vs tracer-off each capture).
+Installed ⇒ one dict + one deque append per span; what that costs on
+the chip is not measured yet (``PERF.md`` §7).
 
 Threading: spans are recorded from compress workers, the H2D thread and
 the consumer concurrently; ``deque.append`` is atomic under the GIL and

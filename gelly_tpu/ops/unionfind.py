@@ -298,17 +298,14 @@ def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
 
 def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
                       valid: jax.Array, unique_cap: int,
-                      tail_cap: int | None = None,
-                      backend: str = "xla",
-                      interpret: bool | None = None) -> jax.Array:
+                      tail_cap: int | None = None) -> jax.Array:
     """Sort-dedup raw-edge fold — the large-chunk RAW device path
     (VERDICT r4 item 4: the generic :func:`union_edges` fixpoint paid
     O(capacity) random gathers per round and ran below one CPU core).
 
-    The measured wall on v5e is random-access throughput (~140M
-    gathers/s regardless of table size), so the design spends REGULAR
-    ops (sorts, cumsum — 5-10x cheaper per lane) to shrink the
-    random-access working set before any union-find work:
+    Random access (element-granule ``p[idx]`` gathers and scatters) is
+    the cost the design cuts: it spends REGULAR ops (sorts, cumsum) to
+    shrink the random-access working set before any union-find work:
 
     1. canonicalize + 2-key sort + first-occurrence mask: exact
        UNDIRECTED dedup. On the power-law streams CC targets, 2^25-edge
@@ -330,39 +327,10 @@ def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
     exact fixpoint over the distinct pairs — both compiled as
     ``lax.cond`` branches that cost nothing when the caps hold.
 
-    Measured 21.5M edges/s at capacity 2^24 on v5e (2^25-edge chunks,
-    Zipf stream) vs 2.06M for :func:`union_edges` — with exact label
-    parity against the chunked numpy oracle.
-
-    ``backend`` selects how the hook rounds' first-level chases execute:
-
-    - ``"xla"`` (default) — plain ``p[idx]`` gathers, the element-granule
-      random-HBM path (~140M touches/s on v5e regardless of table size).
-    - ``"pallas"`` — the distinct pairs' lo endpoints are SORTED (the
-      dedup sort already paid for that order), so their chase runs
-      through :func:`~gelly_tpu.ops.pallas_kernels.sorted_window_gather`:
-      VMEM-resident table windows + one-hot MXU row-select instead of
-      per-lane HBM latency. The kernel is miss-TOLERANT, not
-      miss-approximate: a lane whose index fell outside its tile's
-      window (piecewise-sort seams, adversarial spans) is excluded from
-      that round's hook and forced into the exact tail fixpoint — labels
-      are identical to the XLA backend bit for bit. Requires a capacity
-      :func:`~gelly_tpu.ops.pallas_kernels.gatherable` (multiple of the
-      window span, <= 2^24); ``interpret`` defaults to
-      :func:`~gelly_tpu.ops.pallas_kernels.pallas_interpret` (compiled on
-      TPU, interpreted on the CPU, an error elsewhere).
+    Labels match the chunked numpy oracle exactly. No benchmark cell
+    runs the raw fold, so its speed on the chip is not recorded
+    (``PERF.md``).
     """
-    if backend not in ("xla", "pallas"):
-        raise ValueError(f"backend must be xla/pallas, got {backend!r}")
-    if backend == "pallas":
-        from . import pallas_kernels
-
-        if not pallas_kernels.gatherable(parent.shape[0]):
-            raise ValueError(
-                f"backend='pallas' needs a window-blockable capacity "
-                f"(multiple of {pallas_kernels.GATHER_LANE} lanes spanning "
-                f">= 2 windows, <= 2^24); got {parent.shape[0]}"
-            )
     unique_cap = min(unique_cap, src.shape[0])
     if tail_cap is None:
         tail_cap = max(1 << 16, unique_cap // 4)
@@ -385,26 +353,10 @@ def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
         < jnp.minimum(ucount, unique_cap)
     )
 
-    if backend == "pallas":
-        # Kernel-friendly lo-endpoint view: the live lanes (first ucount,
-        # the flag=0 sort group) are ascending; sentinel/duplicate lanes
-        # map to capacity-1, preserving a sorted tail for the window walk
-        # (their gathers are dead lanes either way).
-        n_cap = parent.shape[0]
-        uu_k = jnp.where(live0, uu_c, jnp.int32(n_cap - 1))
-
     def deduped_fold(p):
         alive = live0
         for depth in (1, 2, 3):
-            if backend == "pallas":
-                from .pallas_kernels import sorted_window_gather
-
-                g1 = sorted_window_gather(p, uu_k, interpret=interpret)
-                hit = g1 >= 0
-                g = jnp.where(hit, g1, 0)
-            else:
-                g = p[uu_c]
-                hit = None
+            g = p[uu_c]
             for _ in range(depth - 1):
                 g = p[g]
             h = p[vv_c]
@@ -414,13 +366,6 @@ def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
             hi = jnp.maximum(g, h)
             alive = live0 & (lo != hi)
             hook = alive & (p[hi] == hi)
-            if hit is not None:
-                # Window-missed lanes: their chased root is unknown, so
-                # they may not hook this round (a wrong-root hook would
-                # merge unrelated components); they stay alive and
-                # resolve in the exact tail fixpoint below.
-                alive = live0 & ((lo != hi) | ~hit)
-                hook = hook & hit
             p = masked_scatter_min(p, hi, lo, hook)
         pos = jnp.cumsum(alive.astype(jnp.int32)) - 1
         nalive = jnp.sum(alive.astype(jnp.int32))

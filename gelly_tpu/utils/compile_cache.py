@@ -1,6 +1,6 @@
 """JAX's persistent compile cache for the entry points.
 
-``chip_smoke.py``, ``bench.py`` and the example CLIs call
+``chip_smoke.py`` and the example CLIs call
 :func:`enable_compile_cache` before their first compile; the package
 itself never does (the test suite runs without a persistent cache).
 """

@@ -7,6 +7,7 @@ collective paths run multi-device without TPU hardware.
 """
 
 import os
+import time
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -48,3 +49,45 @@ REFERENCE_EDGES = [
 @pytest.fixture
 def reference_edges():
     return list(REFERENCE_EDGES)
+
+
+def _await_tenant_windows(eng, tenants, chunks, timeout=120.0):
+    """Block until each tenant of ``eng`` (one tier, ``merge_every=1``)
+    has ``chunks`` chunks folded AND the window that folded the last one
+    is published; fail with what was seen when ``timeout`` passes.
+
+    A window closes right after each dispatch, so window k covers
+    dispatch k: once every position has reached ``chunks``, the engine's
+    dispatch count at that moment is the window number to wait for.
+    ``snapshot_window != 0`` is not enough, since an earlier window
+    satisfies it."""
+    deadline = time.monotonic() + timeout
+    need = None
+    while time.monotonic() < deadline:
+        try:
+            if need is None and all(
+                eng.position(t) >= chunks for t in tenants
+            ):
+                need = eng.stats["dispatches"]
+            if need is not None and all(
+                eng.snapshot_window(t) >= need for t in tenants
+            ):
+                return
+        except KeyError:
+            pass  # auto-admission not seen yet
+        time.sleep(0.02)
+    seen = {}
+    for t in tenants:
+        try:
+            seen[t] = (eng.position(t), eng.snapshot_window(t))
+        except KeyError:
+            seen[t] = "not admitted"
+    raise AssertionError(
+        f"after {timeout} s: (position, snapshot window) per tenant "
+        f"{seen}; wanted position >= {chunks} and window >= {need}"
+    )
+
+
+@pytest.fixture
+def await_tenant_windows():
+    return _await_tenant_windows

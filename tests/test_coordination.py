@@ -89,6 +89,9 @@ def _run_hosts(n, body):
         t.join(60)
     if errs:
         raise errs[0][1]
+    stuck = [k for k, t in enumerate(ts) if t.is_alive()]
+    if stuck:
+        raise AssertionError(f"hosts {stuck} still running after 60 s")
 
 
 # ---------------------------------------------------------------------- #
@@ -309,7 +312,11 @@ def test_reattempted_epoch_converges_over_stale_records(tmp_path):
     out = {}
 
     def body(k):
-        co = Coordinator(str(tmp_path), HostIdentity(k, 2), _cfg())
+        # A loaded run can stall a host thread for seconds: give the
+        # barrier room inside _run_hosts' 60 s join, so a slow run still
+        # converges and a stuck one fails with the barrier's message.
+        co = Coordinator(str(tmp_path), HostIdentity(k, 2),
+                         _cfg(barrier_timeout=45.0))
         _, pos, _ = co.recover(like={"x": np.zeros(4, dtype=np.int64)})
         epoch, agreed = co.agree_position(pos + 4)
         man2 = co.publish(

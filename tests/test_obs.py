@@ -352,14 +352,12 @@ def test_sharded_cc_dirty_row_gauges():
 
 
 # --------------------------------------------------------------------- #
-# overhead smoke (the strict <2% contract is measured on the real
-# streaming_cc_large capture by bench.py's obs block; CI machines are
-# too noisy for 2% — this smoke asserts the plumbing costs little and
-# the results stay bit-identical)
+# overhead smoke (the tracer's cost on the chip is not measured; CI
+# machines are too noisy for a tight bound — this smoke asserts the
+# plumbing costs little and the results stay bit-identical)
 
 
-@pytest.mark.slow  # CI's obs lane runs it (no marker filter there);
-# the strict <2% contract is the bench obs block's, on TPU captures.
+@pytest.mark.slow  # CI's obs lane runs it (no marker filter there).
 def test_tracer_overhead_smoke():
     import time
 

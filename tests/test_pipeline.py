@@ -198,8 +198,8 @@ def test_h2d_depth_validation():
 def test_merge_mode_validation_and_plan_cache_key():
     with pytest.raises(ValueError, match="merge_mode"):
         connected_components(N_V, merge_mode="nope")
-    # Rebinding merge_mode on the same instance must re-jit (cache keys
-    # on it, like fold_backend), not silently reuse stale executables.
+    # Rebinding merge_mode on the same instance must re-jit (the cache
+    # keys on it), not silently reuse stale executables.
     edges = _zipf_edges(n=128)
     agg = connected_components(N_V, merge="gather", codec="sparse",
                                merge_mode="replicated")

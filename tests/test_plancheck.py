@@ -76,10 +76,10 @@ def test_tip_builder_and_knob_discovery_not_vacuous():
                if p.endswith(os.path.join("engine", "aggregation.py"))][0]
     builders = {b.fn.name for b in c._find_builders(agg_mod)}
     assert {"_compiled_plan", "_compiled_tenant_plan"} <= builders
-    assert {"merge_mode", "fold_backend", "merge_degree", "transient",
+    assert {"merge_mode", "merge_degree", "transient",
             "jit_transform", "transform_may_alias",
             "stack_ordered"} <= c._scalar_knobs
-    assert {"merge_mode", "fold_backend"} <= c._str_knobs
+    assert {"merge_mode"} <= c._str_knobs
     assert {"init", "fold", "combine", "host_compress"} \
         <= c._callable_fields
 
@@ -182,10 +182,10 @@ def test_pc101_real_compiled_plan_key_drop_flips(tmp_path):
     # must flag the builder's surviving merge_mode reads.
     with open(AGG_PY) as f:
         src = f.read()
-    needle = "agg.fold_backend, agg.merge_mode, agg.merge_degree,"
-    assert needle in src, "the _compiled_plan key line moved — re-anchor"
-    mutated = src.replace(
-        needle, "agg.fold_backend, agg.merge_degree,")
+    needle = "agg.merge_mode, agg.merge_degree,"
+    assert src.count(needle) == 1, \
+        "the _compiled_plan key line moved — re-anchor"
+    mutated = src.replace(needle, "agg.merge_degree,")
     got = _lint_src(tmp_path, mutated, name="aggregation.py")
     pc101 = [f for f in got if f.rule == "PC101"]
     assert pc101 and all("merge_mode" in f.message for f in pc101), \

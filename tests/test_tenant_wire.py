@@ -272,7 +272,8 @@ def test_shed_tenant_nacks_and_closes_stream():
 # tenant-mode CRC resync
 
 
-def test_tenant_mode_crc_corruption_resyncs_and_completes():
+def test_tenant_mode_crc_corruption_resyncs_and_completes(
+        await_tenant_windows):
     """A corrupt frame in tenant_streams mode cannot name its stream
     (the tenant id lives in the unverifiable payload): the server asks
     for a full resync and the client retransmits every unacked frame —
@@ -303,16 +304,8 @@ def test_tenant_mode_crc_corruption_resyncs_and_completes():
                                       edges[i:i + 16, 1]), tenant=3)
             cli.flush(timeout=30)
             assert left[0] == 0  # the corruption really happened
-            deadline = time.time() + 30
-            while time.time() < deadline:
-                try:
-                    if eng.queue_depth() == 0 and eng.position(3) >= 4:
-                        break
-                except KeyError:
-                    pass  # auto-admission not seen yet
-                time.sleep(0.05)
+            await_tenant_windows(eng, (3,), 4)
             eng.finish(3)
-            assert _wait(lambda: eng.snapshot_window(3) > 0, timeout=10)
             got = eng.labels(3)
         finally:
             cli.close(flush_timeout=None)
@@ -463,7 +456,8 @@ def test_mixed_tenant_stack_refused_whole():
         assert counters.get("ingest.frames_stacked") == 1
 
 
-def test_stacked_tenant_stream_folds_bit_identical_through_router():
+def test_stacked_tenant_stream_folds_bit_identical_through_router(
+        await_tenant_windows):
     """Whole stacks ride the TenantRouter as one drain unit each and
     the folded labels are bit-identical to the in-process engine run —
     stacking is invisible to the tenant fold."""
@@ -482,16 +476,8 @@ def test_stacked_tenant_stream_folds_bit_identical_through_router():
                 cli.send(edge_payload(edges[i:i + 16, 0],
                                       edges[i:i + 16, 1]), tenant=5)
             cli.flush(timeout=30)
-
-            def folded():
-                try:
-                    return eng.position(5) >= 6 and eng.queue_depth() == 0
-                except KeyError:
-                    return False  # auto-admission not seen yet
-
-            assert _wait(folded, timeout=30)
+            await_tenant_windows(eng, (5,), 6)
             eng.finish(5)
-            assert _wait(lambda: eng.snapshot_window(5) > 0, timeout=10)
             got = eng.labels(5)
         finally:
             cli.close(flush_timeout=None)

@@ -814,7 +814,7 @@ def test_compressed_tier_guards():
 
 
 @pytest.mark.ingest
-def test_tenant_router_routes_compressed_streams():
+def test_tenant_router_routes_compressed_streams(await_tenant_windows):
     """Wire leg end to end: clients compress BEFORE send
     (DATA_COMPRESSED + tenant tag), the router submits payloads
     straight into the compressed tier, and the folded labels match the
@@ -857,23 +857,9 @@ def test_tenant_router_routes_compressed_streams():
             for p in payloads_for(t):
                 c.send_compressed(p)
             c.flush()
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            try:
-                if (eng.queue_depth() == 0
-                        and eng.position(3) >= 4
-                        and eng.position(4) >= 4):
-                    break
-            except KeyError:
-                pass  # auto-admission not seen yet
-            time.sleep(0.05)
+        await_tenant_windows(eng, (3, 4), 4)
         for t in (3, 4):
             eng.finish(t)
-        deadline = time.time() + 10
-        while time.time() < deadline and any(
-            eng.snapshot_window(t) == 0 for t in (3, 4)
-        ):
-            time.sleep(0.05)
         got = {t: eng.labels(t) for t in (3, 4)}
     finally:
         eng.stop()
@@ -896,7 +882,7 @@ def test_tenant_router_routes_compressed_streams():
 
 
 @pytest.mark.ingest
-def test_tenant_router_routes_n_client_streams():
+def test_tenant_router_routes_n_client_streams(await_tenant_windows):
     from gelly_tpu.ingest import IngestClient, IngestServer, TenantRouter
     from gelly_tpu.ingest.client import edge_payload
 
@@ -923,23 +909,9 @@ def test_tenant_router_routes_n_client_streams():
                 p["tenant"] = np.array([t], np.int64)
                 c.send(p)
             c.flush()
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            try:
-                if (eng.queue_depth() == 0
-                        and eng.position(7) >= 4
-                        and eng.position(9) >= 4):
-                    break
-            except KeyError:
-                pass  # auto-admission not seen yet
-            time.sleep(0.05)
+        await_tenant_windows(eng, (7, 9), 4)
         for t in (7, 9):
             eng.finish(t)
-        deadline = time.time() + 10
-        while time.time() < deadline and any(
-            eng.snapshot_window(t) == 0 for t in (7, 9)
-        ):
-            time.sleep(0.05)
         got = {t: eng.labels(t) for t in (7, 9)}
     finally:
         eng.stop()

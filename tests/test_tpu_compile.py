@@ -1,10 +1,11 @@
-"""v5e ahead-of-time compiles of the main path's Pallas kernels, of
-the segment fold's lane derivation, and of the sparse degree fold.
+"""v5e ahead-of-time compiles of the wedge Pallas kernel, of the raw
+sort-dedup fold, of the segment fold's lane derivation, and of the
+sparse degree fold.
 
 The TPU compiler is installed here and compiles for a described
 ``v5e:2x2`` topology with no chip attached: it refuses what interpret
 mode accepts (VMEM overruns, unaligned blocks, i64 in a kernel). Every
-case passes ``interpret=False`` and asserts the kernel is in the
+kernel case passes ``interpret=False`` and asserts the kernel is in the
 compiled text. The topology is described only inside the ``topo``
 fixture (libtpu loads once per process; see the on-chip-measurement
 guide, section 2), and the persistent compile cache is off around the
@@ -21,7 +22,6 @@ from gelly_tpu.library import triangles
 from gelly_tpu.ops import pallas_kernels as pk
 
 TABLE = 1 << 24  # north-star vertex capacity
-LANES = 1 << 20  # sorted index lanes per call
 # Largest mask _pick_method lets the wedge kernel take (8064 on v5e).
 WEDGE_MAX_N = max(n for n in range(pk.TILE, 1 << 14, pk.TILE)
                   if pk.wedge_kernel_fits(n))
@@ -60,22 +60,22 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_sorted_window_gather_compiles_at_north_star(one_chip):
-    c = _compile(
-        lambda t, i: pk.sorted_window_gather(t, i, interpret=False),
-        _spec((TABLE,), jnp.int32, one_chip),
-        _spec((LANES,), jnp.int32, one_chip),
-    )
-    assert "tpu_custom_call" in c.as_text()
+def test_raw_dedup_fold_compiles_at_the_smoke_capacity(one_chip):
+    # chip_smoke.py's raw-fold forest (2^24 slots) with the caps the CC
+    # plan's fold passes, over a 2^16-edge chunk: the smoke's 2^22-edge
+    # chunk compiles the same loops and branches, only more slowly.
+    from gelly_tpu.ops import unionfind
 
-
-def test_blocked_gather_compiles_at_north_star(one_chip):
+    e = 1 << 16
     c = _compile(
-        lambda t, i: pk.blocked_gather(t, i, interpret=False),
+        lambda p, s, d, v: unionfind.union_edges_dedup(
+            p, s, d, v, unique_cap=max(1 << 20, 3 * (e >> 4))),
         _spec((TABLE,), jnp.int32, one_chip),
-        _spec((LANES,), jnp.int32, one_chip),
+        _spec((e,), jnp.int32, one_chip), _spec((e,), jnp.int32, one_chip),
+        _spec((e,), jnp.bool_, one_chip),
     )
-    assert "tpu_custom_call" in c.as_text()
+    text = c.as_text()
+    assert " sort(" in text and "tpu_custom_call" not in text
 
 
 def test_wedge_kernel_compiles_at_the_largest_auto_size(one_chip):

@@ -301,6 +301,7 @@ def test_sparse_exact_degree_skew_raises():
         exact_triangle_count(s, max_degree=8).final_counts()
 
 
+@pytest.mark.pallas
 def test_window_triangles_mxu_kernel_matches_gather():
     # Pallas MXU wedge-matrix path (interpret mode on CPU) == VPU gather path.
     s = edge_stream_from_edges(
@@ -312,6 +313,7 @@ def test_window_triangles_mxu_kernel_matches_gather():
     assert got == {0: 2, 1: 3, 2: 2}
 
 
+@pytest.mark.pallas
 def test_wedge_count_matrix_random():
     import jax.numpy as jnp
 
