@@ -156,46 +156,12 @@ def _chase_roots(p: jax.Array, x: jax.Array) -> jax.Array:
     return x
 
 
-def _rooted_fixpoint(parent: jax.Array, src: jax.Array, rv_fn,
-                     valid: jax.Array, live0) -> jax.Array:
-    """Shared exact hook loop of the pair-sized union kernels: per round,
-    chase ``src`` to true roots, resolve the partner roots with
-    ``rv_fn(p, ru)``, hook root-to-root with one scatter-min; exit when no
-    pair is live. ``live0`` short-circuits the whole loop (a while_loop
-    whose initial predicate is False runs zero iterations).
-
-    Invariants: hooks write ``lo < p[hi] = hi`` at true roots only, so
-    chains stay strictly decreasing (acyclic, ``p[i] <= i``) and every
-    live round strictly lowers some entry (termination). At exit all pairs
-    connect (equal roots) and hooks only ever merge pair-connected trees
-    (no spurious unions).
-    """
-
-    def cond(state):
-        return state[1]
-
-    def body(state):
-        p, _ = state
-        ru = _chase_roots(p, src)
-        rv = rv_fn(p, ru)
-        with jax.named_scope("uf.hook"):
-            lo = jnp.minimum(ru, rv)
-            hi = jnp.maximum(ru, rv)
-            live = valid & (lo != hi)
-            p2 = masked_scatter_min(p, hi, lo, live)
-            return p2, jnp.any(live)
-
-    with jax.named_scope("uf.fixpoint"):
-        p, _ = jax.lax.while_loop(cond, body, (parent, live0))
-    return p
-
-
 def union_pairs_rooted(parent: jax.Array, src: jax.Array, dst: jax.Array,
                        valid: jax.Array) -> jax.Array:
     """Union (src, dst) pairs with ALL per-round work sized to the pairs —
     the generic exact kernel of the compact-space plans (the hot star-
-    forest fold, :func:`union_pairs_star`, shares its loop via
-    :func:`_rooted_fixpoint` and adds unrolled fast rounds in front).
+    forest fold, :func:`union_pairs_star`, runs it on the pairs its
+    unrolled fast rounds leave unresolved).
 
     Unlike :func:`union_edges` (whose every round walks the full parent
     array for the doubling step) and :func:`union_pairs_compact` (which
@@ -221,51 +187,39 @@ def union_pairs_rooted(parent: jax.Array, src: jax.Array, dst: jax.Array,
     """
     src = jnp.where(valid, src, 0)
     dst = jnp.where(valid, dst, 0)
-    return _rooted_fixpoint(
-        parent, src, lambda p, ru: _chase_roots(p, dst), valid,
-        jnp.bool_(True),
-    )
+
+    def cond(state):
+        return state[1]
+
+    def body(state):
+        p, _ = state
+        ru = _chase_roots(p, src)
+        rv = _chase_roots(p, dst)
+        with jax.named_scope("uf.hook"):
+            lo = jnp.minimum(ru, rv)
+            hi = jnp.maximum(ru, rv)
+            live = valid & (lo != hi)
+            p2 = masked_scatter_min(p, hi, lo, live)
+            return p2, jnp.any(live)
+
+    with jax.named_scope("uf.fixpoint"):
+        p, _ = jax.lax.while_loop(cond, body, (parent, jnp.bool_(True)))
+    return p
 
 
-def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
-                     valid: jax.Array,
-                     fast_depths: tuple[int, ...] = (2, 3),
-                     check_depth: int = 3) -> jax.Array:
-    """Union star-forest payload rows — the hot compact-codec fold kernel.
+def star_tail_width(lanes: int) -> int:
+    """Lanes of the star fold's exact tail for a payload of ``lanes``
+    lanes: 1/32 of them, at least 1024, at most all of them."""
+    return min(lanes, max(1024, lanes // 32))
 
-    ``(v[j], v[ri[j]])`` are the pairs: every payload row is a host-combined
-    spanning forest whose root is itself a row entry, and the codec ships
-    the root's row INDEX (``ri``), so the root side of each pair resolves
-    with one pair-sized gather from the already-chased array (``rv =
-    ru[ri]``) instead of a second pointer chase.
 
-    Structure (everything sized to the pairs — no O(M) work):
-
-    1. one UNROLLED round per ``fast_depths`` entry: a fixed-depth pointer
-       chase of that many levels (straight-line gathers, no while_loop —
-       measured on v5e, loop iterations cost ~15ms of control overhead
-       each, ~1.8x the 2M-lane gather they wrap) followed by one
-       scatter-min hook MASKED to verified roots (``p[hi] == hi``) — a
-       hook at an interior node would replace a real parent edge and
-       disconnect its ancestors, losing earlier dispatches' unions. Two
-       rounds (depths 2 then 3) measured fully convergent on Zipf
-       payload streams, not on Twitter-2010's degree law: there the exact
-       fixpoint (step 3) still ran 103 rounds over 64 dispatches on a
-       v5e (PERF.md §5).
-    2. a depth-limited convergence check: equal depth-limited labels imply
-       same tree (chases are deterministic), so ``any(live) == False`` here
-       PROVES convergence and skips step 3 entirely (a while_loop whose
-       initial predicate is False runs zero iterations).
-    3. an exact fixpoint fallback (true-root chase per round, shared with
-       :func:`union_pairs_rooted`) for whatever the fast pass leaves
-       unresolved — short chases, hook conflicts, root-mask rejections.
-       Correctness never depends on the unrolled depth.
-
-    Like :func:`union_pairs_rooted`, the forest is returned without a
-    global flatten; the window-close transform pays the one full-array
-    pointer_jump.
-    """
-    v = jnp.where(valid, v, 0)
+def _star_check(parent: jax.Array, v: jax.Array, ri: jax.Array,
+                valid: jax.Array):
+    """Steps 1 and 2 of :func:`union_pairs_star` over pairs ``(v[j],
+    v[ri[j]])`` with ``v`` already zeroed at invalid lanes: the fast
+    rounds, then the depth-3 check. Returns the forest after the fast
+    rounds, each lane's two depth-3 labels ``(a, b)``, and the lanes
+    whose labels differ (``live``)."""
 
     def chase_fixed(p, x, depth):
         g = p[x]
@@ -275,7 +229,7 @@ def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
 
     p = parent
     with jax.named_scope("uf.fast"):
-        for depth in fast_depths:
+        for depth in (2, 3):
             ru = chase_fixed(p, v, depth)
             rv = ru[ri]
             lo = jnp.minimum(ru, rv)
@@ -286,14 +240,104 @@ def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
             # silently splitting a component built by earlier dispatches
             # (a root's self-loop is the only edge safe to overwrite).
             # Pairs whose chase fell short stay live for the check below
-            # and resolve in the exact fixpoint.
+            # and resolve in the exact tail.
             live = valid & (lo != hi) & (p[hi] == hi)
             p = masked_scatter_min(p, hi, lo, live)
 
     with jax.named_scope("uf.check"):
-        ru = chase_fixed(p, v, check_depth)
-        live0 = jnp.any(valid & (ru != ru[ri]))
-    return _rooted_fixpoint(p, v, lambda p_, ru_: ru_[ri], valid, live0)
+        a = chase_fixed(p, v, 3)
+        b = a[ri]
+        live = valid & (a != b)
+    return p, a, b, live
+
+
+def _live_tail(p: jax.Array, a: jax.Array, b: jax.Array, live: jax.Array,
+               width: int) -> jax.Array:
+    """Union the pairs ``(a[j], b[j])`` of the ``live`` lanes exactly,
+    ``width`` at a time.
+
+    One running sum ranks the live lanes. Each batch scatters the lane
+    indices of ranks ``[k * width, (k + 1) * width)`` into ``width``
+    slots, gathers their pairs and runs the exact fixpoint on them,
+    chasing both sides; batches repeat while ranks remain, so no live
+    lane is ever dropped. A payload with no live lane runs no batch.
+
+    On a v5e, over 1,572,864 lanes, the running sum and scatter take
+    ~8.4 ms a batch, less than ``jnp.nonzero`` (~110 ms) or
+    ``collectives.compact_delta`` (~18 ms) take (PERF.md §6).
+    """
+    lanes = live.shape[0]
+    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
+    n_live = jnp.sum(live.astype(jnp.int32))
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    slot = jnp.arange(width, dtype=jnp.int32)
+
+    def cond(state):
+        return state[1] < n_live
+
+    def body(state):
+        p, first = state
+        at = rank - first
+        take = live & (at >= 0) & (at < width)
+        # Slot ``width`` lies past the end: lanes outside the batch drop.
+        idx = jnp.zeros((width,), jnp.int32).at[
+            jnp.where(take, at, width)].set(lane, mode="drop")
+        ok = slot < n_live - first
+        return union_pairs_rooted(p, a[idx], b[idx], ok), first + width
+
+    p, _ = jax.lax.while_loop(cond, body, (p, jnp.int32(0)))
+    return p
+
+
+def union_pairs_star(parent: jax.Array, v: jax.Array, ri: jax.Array,
+                     valid: jax.Array) -> jax.Array:
+    """Union star-forest payload rows — the hot compact-codec fold kernel.
+
+    ``(v[j], v[ri[j]])`` are the pairs: every payload row is a host-combined
+    spanning forest whose root is itself a row entry, and the codec ships
+    the root's row INDEX (``ri``), so the root side of each pair resolves
+    with one pair-sized gather from the already-chased array (``rv =
+    ru[ri]``) instead of a second pointer chase.
+
+    Structure (everything sized to the pairs — no O(M) work):
+
+    1. two UNROLLED rounds: a pointer chase of 2, then 3, levels
+       (straight-line gathers), each followed by one scatter-min hook
+       MASKED to verified roots (``p[hi] == hi``) — a hook at an
+       interior node would replace a real parent edge and disconnect
+       its ancestors, losing earlier dispatches' unions.
+    2. a depth-limited check: each lane's two depth-3 labels ``(a, b)``.
+       Equal labels imply the same tree (chases are deterministic), and
+       unions only merge trees, so such a lane is resolved for good;
+       the lanes whose labels differ are ``live``.
+       On Twitter-2010's degree law about 1% of a dispatch's lanes are
+       live here (PERF.md §5).
+    3. an exact tail over the live lanes only: they are ranked by one
+       running sum and taken :func:`star_tail_width` of the lane count
+       at a time, each batch running the exact fixpoint
+       (:func:`union_pairs_rooted`: a true-root chase of both sides and
+       one hook per round) on its pairs ``(a[j], b[j])``. Those are ancestors of ``v[j]`` and
+       ``v[ri[j]]``, so their true roots, and every hook, are those of
+       the pairs themselves. Batches repeat while live lanes remain, so
+       correctness never depends on the unrolled depth or the width.
+       On a v5e a chase step costs what its gather costs, ~30 ns a lane
+       (1.5 ms over 49,152 lanes, 30–46 ms over 1,572,864); the loop's
+       own condition takes ~1 µs a step (PERF.md §5).
+
+    With every live lane in one batch, each round hooks exactly the
+    ``(hi, lo)`` roots a fixpoint over all lanes would (lanes resolved
+    at the check never hook), so the forest is bit-identical to that
+    fixpoint's. With several batches the components are the same; which
+    root hooks under which may differ.
+
+    Like :func:`union_pairs_rooted`, the forest is returned without a
+    global flatten; the window-close transform pays the one full-array
+    pointer_jump.
+    """
+    v = jnp.where(valid, v, 0)
+    p, a, b, live = _star_check(parent, v, ri, valid)
+    with jax.named_scope("uf.tail"):
+        return _live_tail(p, a, b, live, star_tail_width(v.shape[0]))
 
 
 def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
@@ -317,8 +361,7 @@ def union_edges_dedup(parent: jax.Array, src: jax.Array, dst: jax.Array,
        component).
     4. survivors (pre-hook depth-3 view, conservative) compact into
        ``tail_cap`` lanes via cumsum+scatter and finish in the EXACT
-       pair-sized fixpoint (:func:`_rooted_fixpoint` via
-       :func:`union_pairs_rooted`).
+       pair-sized fixpoint (:func:`union_pairs_rooted`).
     5. one ``p[p]`` halving keeps entry depth low for the next chunk.
 
     Exactness never depends on the caps: ``unique_cap`` overflow (more

@@ -30,7 +30,7 @@ from gelly_tpu.utils.metrics import StageTimer, trace
 
 N_V = 512
 
-FOLD_SCOPES = ["cc.fold", "cc.decode", "uf.fast", "uf.check",
+FOLD_SCOPES = ["cc.fold", "cc.decode", "uf.fast", "uf.check", "uf.tail",
                "uf.fixpoint", "uf.chase", "uf.hook"]
 CLOSE_SCOPES = ["cc.close", "cc.close.jump", "cc.close.canon",
                 "cc.close.labels"]
@@ -133,7 +133,9 @@ def test_named_scopes_reach_the_compiled_program(program):
     names = _op_names(fn, *args)
     for scope in want:
         assert any(f"/{scope}/" in n for n in names), (scope, sorted(names))
-    # the hook and the chase run inside the exact fixpoint's loop body
+    # the hook and the chase run inside the exact fixpoint's loop body,
+    # and the fixpoint inside the live-lane tail's batch loop
     if program != "transform":
         assert any("/uf.fixpoint/while/body/uf.hook/" in n for n in names)
         assert any("/uf.fixpoint/while/body/uf.chase/" in n for n in names)
+        assert any("/uf.tail/while/body/uf.fixpoint/" in n for n in names)

@@ -1,6 +1,6 @@
 """v5e ahead-of-time compiles of the wedge Pallas kernel, of the raw
-sort-dedup fold, of the segment fold's lane derivation, and of the
-sparse degree fold.
+sort-dedup fold, of the segment fold's lane derivation, of the star
+fold's live-lane tail, and of the sparse degree fold.
 
 The TPU compiler is installed here and compiles for a described
 ``v5e:2x2`` topology with no chip attached: it refuses what interpret
@@ -105,6 +105,27 @@ def test_lane_segment_starts_compiles_without_a_loop(one_chip):
                  _spec((1, 1 << 19), jnp.int32, one_chip))
     text = c.as_text()
     assert " scatter(" in text and " while(" not in text
+
+
+def test_star_fold_chases_only_the_live_tail_at_the_cell_shape(one_chip):
+    # The star fold at the CC cell's fold shape (1,572,864 lanes into
+    # the compact forest of 41,652,230 slots): every true-root chase
+    # gathers over the tail's 49,152 lanes, none over the payload's.
+    from gelly_tpu.ops import unionfind
+
+    n, lanes = 41_652_230, 6 << 18
+    width = unionfind.star_tail_width(lanes)
+    assert width == 49_152
+    c = _compile(unionfind.union_pairs_star,
+                 _spec((n,), jnp.int32, one_chip),
+                 _spec((lanes,), jnp.int32, one_chip),
+                 _spec((lanes,), jnp.int32, one_chip),
+                 _spec((lanes,), jnp.bool_, one_chip))
+    chase = [ln for ln in c.as_text().splitlines()
+             if "/uf.tail/while/body/uf.fixpoint/" in ln
+             and "/uf.chase/" in ln]
+    assert any(f"[{width}]" in ln for ln in chase)
+    assert not any(f"[{lanes}]" in ln for ln in chase)
 
 
 def _degree_fold_text(one_chip, delta_dtype) -> list:

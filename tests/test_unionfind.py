@@ -261,6 +261,222 @@ def test_union_pairs_star_sequential_calls_fuzz():
         assert got == want, (seed, got, want)
 
 
+def _full_width_star(parent, v, ri, valid):
+    """The star fold whose exact fixpoint chased every lane of the
+    payload each round: the reference the live-lane tail must match bit
+    for bit while its live lanes fit one batch."""
+    import jax
+
+    from gelly_tpu.ops.segments import masked_scatter_min
+
+    v = jnp.where(valid, v, 0)
+
+    def chase_fixed(p, x, depth):
+        g = p[x]
+        for _ in range(depth - 1):
+            g = p[g]
+        return g
+
+    p = parent
+    for depth in (2, 3):
+        ru = chase_fixed(p, v, depth)
+        rv = ru[ri]
+        lo, hi = jnp.minimum(ru, rv), jnp.maximum(ru, rv)
+        p = masked_scatter_min(p, hi, lo,
+                               valid & (lo != hi) & (p[hi] == hi))
+    ru = chase_fixed(p, v, 3)
+    live0 = jnp.any(valid & (ru != ru[ri]))
+
+    def roots(p, x):
+        return jax.lax.while_loop(lambda s: jnp.any(s[1] != s[0]),
+                                  lambda s: (s[1], p[s[1]]), (x, p[x]))[0]
+
+    def body(state):
+        p, _ = state
+        ru = roots(p, v)
+        rv = ru[ri]
+        lo, hi = jnp.minimum(ru, rv), jnp.maximum(ru, rv)
+        live = valid & (lo != hi)
+        return masked_scatter_min(p, hi, lo, live), jnp.any(live)
+
+    return jax.lax.while_loop(lambda s: s[1], body, (p, live0))[0]
+
+
+def _star_payloads(seed, m=64, lanes=48, calls=16):
+    """Sequential star payloads over ``m`` slots, ``lanes`` lanes each:
+    a few rows of distinct vertices, each lane's ``ri`` its row's root
+    lane, the rest invalid."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(calls):
+        v = np.zeros(lanes, np.int32)
+        ri = np.zeros(lanes, np.int32)
+        valid = np.zeros(lanes, bool)
+        at = 0
+        while at < lanes - 1:
+            n_row = int(rng.integers(2, min(12, lanes - at) + 1))
+            v[at:at + n_row] = rng.permutation(m)[:n_row]
+            ri[at:at + n_row] = at + int(rng.integers(0, n_row))
+            valid[at:at + n_row] = rng.random() < 0.9
+            at += n_row
+        out.append((jnp.asarray(v), jnp.asarray(ri), jnp.asarray(valid)))
+    return out
+
+
+def _chain_payloads(lanes=48):
+    """Eight chains of depth 5 in slots ``8c + 1 .. 8c + 6``, built top
+    down one link a call, then one call joining each chain's deep end
+    ``8c + 6`` to slot ``8c``: the depth-3 chases stop inside the chains,
+    so that call leaves eight lanes live whose unions are independent."""
+    calls = [[(8 * c + 6 - k, 8 * c + 5 - k) for c in range(8)]
+             for k in range(5)]
+    calls.append([(8 * c + 6, 8 * c) for c in range(8)])
+    out = []
+    for rows in calls:
+        v = np.zeros(lanes, np.int32)
+        ri = np.zeros(lanes, np.int32)
+        valid = np.zeros(lanes, bool)
+        for r, (a, root) in enumerate(rows):
+            v[2 * r:2 * r + 2] = (root, a)
+            ri[2 * r:2 * r + 2] = 2 * r
+            valid[2 * r:2 * r + 2] = True
+        out.append((jnp.asarray(v), jnp.asarray(ri), jnp.asarray(valid)))
+    return out
+
+
+def _live_count(p, v, ri, valid):
+    from gelly_tpu.ops.unionfind import _star_check
+
+    return int(_star_check(p, jnp.where(valid, v, 0), ri, valid)[3].sum())
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3, "chains"])
+def test_union_pairs_star_bit_identical_to_full_width_fixpoint(seed):
+    # Every live lane fits one batch (the default width of 48 lanes is
+    # all of them), so each call's forest equals the full-width
+    # fixpoint's exactly, over sequential calls on a never-flattened
+    # forest.
+    import jax
+
+    from gelly_tpu.ops.unionfind import union_pairs_star
+
+    star, ref = jax.jit(union_pairs_star), jax.jit(_full_width_star)
+    p = q = fresh_forest(64)
+    live = 0
+    payloads = (_chain_payloads() if seed == "chains"
+                else _star_payloads(seed))
+    for v, ri, valid in payloads:
+        live += _live_count(p, v, ri, valid)
+        p, q = star(p, v, ri, valid), ref(q, v, ri, valid)
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(q))
+    assert live > 0  # the tail ran
+
+
+@pytest.mark.parametrize("width", [1, 2, 5])
+def test_union_pairs_star_tail_runs_several_batches(width, monkeypatch):
+    import jax
+
+    from gelly_tpu.ops import unionfind
+
+    monkeypatch.setattr(unionfind, "star_tail_width", lambda lanes: width)
+    # A function of its own, so jit traces it with the patched width
+    # rather than reuse another test's trace of union_pairs_star.
+    star = jax.jit(lambda *a: unionfind.union_pairs_star(*a))
+    ref = jax.jit(_full_width_star)
+    p = q = fresh_forest(64)
+    pairs, live = [], []
+    for v, ri, valid in _chain_payloads() + _star_payloads(1):
+        live.append(_live_count(p, v, ri, valid))
+        p, q = star(p, v, ri, valid), ref(q, v, ri, valid)
+        vv, rr, ok = map(np.asarray, (v, ri, valid))
+        pairs += [(int(vv[j]), int(vv[rr[j]])) for j in np.flatnonzero(ok)]
+        assert labels_of(p, 64) == labels_of(q, 64)
+    assert live[5] == 8 > width  # the joining call took several batches
+    assert labels_of(p, 64) == _pair_oracle(64, pairs)
+    pp = np.asarray(p)
+    assert (pp <= np.arange(64)).all()  # still a min-rooted forest
+
+
+@pytest.mark.parametrize("case", ["repeated_rows", "no_valid_lane"])
+def test_union_pairs_star_payload_with_no_live_lane(case):
+    import jax
+
+    from gelly_tpu.ops.unionfind import union_pairs_star
+
+    star = jax.jit(union_pairs_star)
+    v, ri, valid = _star_payloads(3, calls=1)[0]
+    # Flat, so every pair the first call joined reads one label at the
+    # check's depth.
+    p = pointer_jump(star(fresh_forest(64), v, ri, valid))
+    if case == "no_valid_lane":
+        valid = jnp.zeros_like(valid)
+    assert _live_count(p, v, ri, valid) == 0
+    out = star(p, v, ri, valid)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(p))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(_full_width_star(p, v, ri, valid)))
+
+
+@pytest.mark.parametrize("wire", ["segments", "pairs"])
+def test_star_tail_takes_one_batch_a_dispatch_on_a_power_law_stream(wire):
+    # Twitter-2010's degree law at 2^16 slots: 2^18 edges in 64 chunks
+    # of 2^12, one dispatch each through the compact plan, as the
+    # benchmark's CC cell folds them. Every dispatch's live lanes fit
+    # the derived tail width (one batch), some dispatch runs the tail
+    # and some runs none, and the labels are exact.
+    import os
+    import sys
+
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark import synth
+    from gelly_tpu.library.connected_components import (
+        cc_labels_numpy,
+        connected_components_compact,
+        lane_segment_starts,
+    )
+    from gelly_tpu.ops.unionfind import _star_check, star_tail_width
+
+    n = 1 << 16
+    src, dst = synth.edges(
+        {"vertices": n, "graph_vertices": n - n // 100, "edges": 1 << 18,
+         "degree_exponent": 2.276, "graph_seed": 777}, 2147483013)
+    agg = connected_components_compact(n, compact_capacity=n, wire=wire)
+    fold = jax.jit(agg.fold_compressed)
+    check = jax.jit(_star_check)
+
+    class Chunk:
+        def __init__(self, a, b):
+            self.src, self.dst = a, b
+            self.valid = np.ones(a.shape[0], bool)
+
+    s, live = agg.init(), []
+    for i in range(0, src.shape[0], 1 << 12):
+        pl = agg.stack_payloads(
+            [agg.host_compress(Chunk(src[i:i + 4096], dst[i:i + 4096]))])
+        if wire == "segments":
+            mm = jnp.atleast_2d(pl["m"])
+            ri, ok = lane_segment_starts(jnp.atleast_2d(pl["len"]),
+                                         mm.shape[1])
+            v, ri, ok = mm.reshape(-1), ri.reshape(-1), ok.reshape(-1)
+        else:
+            vv, rr = jnp.atleast_2d(pl["v"]), jnp.atleast_2d(pl["ri"])
+            ri = (rr + vv.shape[1] * jnp.arange(vv.shape[0])[:, None])
+            v, ri = vv.reshape(-1), ri.reshape(-1)
+            ok = v >= 0
+        live.append((int(check(s.croot, jnp.where(ok, v, 0), ri,
+                               ok)[3].sum()), v.shape[0]))
+        s = fold(s, pl)
+    assert len(live) == 64
+    assert all(k <= star_tail_width(lanes) for k, lanes in live), live
+    assert min(k for k, _ in live) == 0 < max(k for k, _ in live)
+    np.testing.assert_array_equal(np.asarray(agg.transform(s)),
+                                  cc_labels_numpy(src, dst, None, n))
+
+
 # ------------------- sort-dedup raw fold (round 5) -------------------- #
 
 
