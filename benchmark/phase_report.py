@@ -49,8 +49,9 @@ def _per_medge(seconds, edges):
 
 
 def derived(ps: dict, edges: int, fold_program: str,
-            close_program: str) -> dict:
-    """The per-layer readings of one pass (None where it holds none)."""
+            close_program: str | None) -> dict:
+    """The per-layer readings of one pass (None where it holds none, as
+    the close's do on a plan without a close)."""
     ph = ps["phases"] or {}
     phases, execs = ph.get("phases", {}), ph.get("phase_execs", {})
     progs = ph.get("programs", {})
@@ -102,7 +103,8 @@ def report(cell, seed: int, passes: int = 1,
         rep["warmup_s"] = time.perf_counter() - t
         prof = common.Profiler(cell.name + ".phases")
         fold = "jit_" + agg.fold_compressed.__name__
-        close = "jit_" + agg.transform.__name__
+        close = ("jit_" + agg.transform.__name__ if agg.transform
+                 else None)
         for i in range(passes):
             timer = StageTimer()
             c0 = _counters()

@@ -202,3 +202,15 @@ def test_phase_report_on_a_tiny_cell(tmp_path):
         assert ps["phases"] is None
         assert ps["derived"]["fold_device_ms_per_medge"] is None
     assert rep["passes"][0]["counters"] == rep["passes"][1]["counters"]
+
+
+def test_phase_report_on_a_plan_without_a_close():
+    from benchmark import phase_report
+
+    from .test_degrees_cell import tiny_degrees_cell
+
+    rep = phase_report.report(tiny_degrees_cell(), 2**31 + 304, passes=1)
+    (ps,) = rep["passes"]
+    assert ps["chunks"] > 0 and ps["counters"]["deg.fold_pairs"] > 0
+    assert ps["derived"]["close_program_device_ms_per_medge"] is None
+    assert ps["derived"]["close_device_ms_per_medge"] is None
